@@ -84,9 +84,9 @@ def _backend_invariance() -> dict:
 
     a = Analysis(BACKEND_PROBE, dense_threshold=DENSE_THRESHOLD)
     fiedler = a.fiedler                 # canonical: backend-independent
+    routing = analyze_routing(a.topo)   # float64 sigma DP: always on ref
     out = {}
     for backend in ("ref", "pallas_interpret"):
-        routing = analyze_routing(a.topo, backend=backend)
         out[backend] = {
             s: evaluate_traffic(a.topo, "adversarial", scheme=s,
                                 routing=routing, fiedler=fiedler,

@@ -226,6 +226,8 @@ def main(argv: List[str] = None) -> int:
     unknown = [n for n in names if n not in BENCHES]
     if unknown:
         ap.error(f"unknown bench name(s) {unknown}; known: {list(BENCHES)}")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     events: List[dict] = []
     for name in names:
         events += _run_instrumented(name)

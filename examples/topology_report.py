@@ -38,6 +38,7 @@ import contextlib
 
 from repro import obs
 from repro.api import Analysis, REGISTRY
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def list_families() -> str:
@@ -84,6 +85,7 @@ def main():
         if not args.spec:
             ap.error("a topology spec is required (see the list above)")
         return
+    enable_compile_cache()
     with contextlib.ExitStack() as stack:
         if args.trace:
             stack.enter_context(obs.tracing(args.trace))

@@ -9,8 +9,7 @@ comparison.  The backend auto-selects by ``n``:
   exact Fiedler vector);
 * larger — the matrix-free JAX Lanczos path (``rho2_lanczos``, top-Ritz
   Fiedler approximation) through the :mod:`repro.kernels.spmv` dispatcher
-  (the Pallas kernel wherever it compiles, the jnp reference elsewhere;
-  ``use_pallas_kernel=True`` forces the kernel path), so device-scale
+  (``use_pallas_kernel=True`` forces the kernel path), so device-scale
   instances never pay a dense eigendecomposition.
 
 Nothing is computed in ``__init__``; every property memoizes on first access,

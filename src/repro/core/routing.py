@@ -29,14 +29,12 @@ time; histograms count ordered (source, target) pairs.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro import obs
 from repro.kernels import spmv as KS
@@ -46,7 +44,7 @@ from .graphs import Topology
 __all__ = [
     "RoutingResult", "bfs_distances", "shortest_path_counts",
     "analyze_routing", "routing_stats_stacked", "sample_sources",
-    "reverse_slot_index", "DEFAULT_SOURCE_CHUNK",
+    "reverse_slot_index", "exact_counts", "DEFAULT_SOURCE_CHUNK",
 ]
 
 #: sources per jitted BFS/path-count call — bounds the (chunk, n, k) gather
@@ -86,9 +84,21 @@ def _bfs_dist_chunk(table: jnp.ndarray, dist0: jnp.ndarray) -> jnp.ndarray:
     return dist
 
 
-@functools.partial(jax.jit, static_argnames=("backend",))
-def _sigma_chunk(table: jnp.ndarray, dist: jnp.ndarray,
-                 backend: Optional[str] = None) -> jnp.ndarray:
+def exact_counts():
+    """The float64 scope of the exact path- and walk-count DPs: the sigma DP
+    here and the KSP walk DP in :mod:`repro.core.traffic` trace inside it.
+
+    float32 counts go inexact past 2^24, and high-diversity families blow
+    through that well before n = 10^5 (torus(32, 2)'s antipodal pairs have
+    4 * C(32, 16) ≈ 2.4e9 minimal paths).  Inside the scope the DPs apply
+    their spmv on the ``ref`` backend: the Pallas kernel is float32-only,
+    because Mosaic has no float64.
+    """
+    return jax.enable_x64(True)
+
+
+@jax.jit
+def _sigma_chunk(table: jnp.ndarray, dist: jnp.ndarray) -> jnp.ndarray:
     """Minimal-path counts sigma(s, v) for a (S, n) block of BFS distances.
 
     Layered DP over the BFS DAG: sigma at layer d is the sum of sigma over
@@ -96,16 +106,15 @@ def _sigma_chunk(table: jnp.ndarray, dist: jnp.ndarray,
     :mod:`repro.kernels.spmv` dispatcher.  Self-padded entries contribute
     nothing because a vertex is never in the layer preceding its own.
 
-    Accumulates in float64 when x64 is enabled at trace time (the
-    :func:`shortest_path_counts` entry point wraps its calls in
-    ``enable_x64``): float32 counts go inexact past 2^24 and high-diversity
-    expanders blow through that well before n=10^5 — e.g. torus(32, 2)'s
-    antipodal pairs have C(32, 16) ≈ 6.0e8 minimal paths.
+    Accumulates in float64 when traced inside :func:`exact_counts` (as
+    :func:`shortest_path_counts` does), on the ``ref`` spmv backend; outside
+    it (the simulator's stacked fault rounds, which need only count ratios)
+    in float32 on the default backend.
     """
     obs.count("jit_trace/sigma_dp")              # trace-time increment
-    bk = KS.resolve_backend(backend)
     dmax = jnp.maximum(dist.max(), 0)
     acc_dt = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+    bk = "ref" if acc_dt == jnp.float64 else KS.resolve_backend()
     sigma0 = (dist == 0).astype(acc_dt)
 
     def body(d, sigma):
@@ -153,31 +162,27 @@ def bfs_distances(table: np.ndarray, sources: Optional[Sequence[int]] = None,
 
 
 def shortest_path_counts(table: np.ndarray, dist: np.ndarray,
-                         chunk: int = DEFAULT_SOURCE_CHUNK,
-                         backend: Optional[str] = None) -> np.ndarray:
+                         chunk: int = DEFAULT_SOURCE_CHUNK) -> np.ndarray:
     """Minimal-path counts sigma(s, t) for precomputed BFS distances.
 
     Args:
         table: (n, k) padded neighbor table (same one ``dist`` came from).
         dist: (S, n) int32 output of :func:`bfs_distances`.
         chunk: sources per jitted call.
-        backend: spmv backend for the layered DP (default: dispatcher's).
 
     Returns:
         (S, n) float64 counts of distinct shortest s→t paths (parallel edges
         count as distinct paths); 0 for unreachable targets, 1 on the diagonal.
-        The DP runs in float64 (``enable_x64`` scope), so counts are exact
-        integers up to 2^53 — past the 2^24 ceiling the old float32
-        accumulator hit on high-diversity families like torus(32, 2).
+        The DP runs in float64 (:func:`exact_counts`), so counts are exact
+        integers up to 2^53.
     """
     table = np.asarray(table)
     tab = jnp.asarray(table, dtype=jnp.int32)
     out = np.empty(dist.shape, dtype=np.float64)
-    with enable_x64():
+    with exact_counts():
         for lo, hi in _chunks(dist.shape[0], chunk):
             out[lo:hi] = np.asarray(
-                _sigma_chunk(tab, jnp.asarray(dist[lo:hi]), backend=backend),
-                dtype=np.float64)
+                _sigma_chunk(tab, jnp.asarray(dist[lo:hi])), dtype=np.float64)
     return out
 
 
@@ -337,8 +342,7 @@ def analyze_routing(topo: Union[Topology, Tuple[np.ndarray, int]],
                     sample_fraction: Optional[float] = None,
                     seed: int = 0,
                     bootstrap: int = 256,
-                    confidence: float = 0.95,
-                    backend: Optional[str] = None) -> RoutingResult:
+                    confidence: float = 0.95) -> RoutingResult:
     """Path-level analysis of one topology via batched BFS, exact or sampled.
 
     Args:
@@ -356,7 +360,6 @@ def analyze_routing(topo: Union[Topology, Tuple[np.ndarray, int]],
         seed: source-sampling seed (also seeds the bootstrap resampler).
         bootstrap: bootstrap replicates for the CI.
         confidence: CI coverage level (default 95%).
-        backend: spmv backend for the sigma DP (default: dispatcher's).
 
     Returns:
         :class:`RoutingResult` with distances, path counts, and summary stats.
@@ -382,7 +385,7 @@ def analyze_routing(topo: Union[Topology, Tuple[np.ndarray, int]],
         srcs = np.asarray(list(sources), dtype=np.int64)
     obs.count("routing/bfs_sources", int(srcs.size))
     dist = bfs_distances(table, srcs, chunk=chunk)
-    sigma = shortest_path_counts(table, dist, chunk=chunk, backend=backend)
+    sigma = shortest_path_counts(table, dist, chunk=chunk)
     finite = dist >= 0
     offdiag = finite.copy()
     offdiag[np.arange(srcs.size), srcs] = False   # drop s == t pairs

@@ -4,14 +4,14 @@ The dense path is the test oracle and handles n <= ~4096.  The Lanczos path is
 the production solver: it never materializes the n x n matrix — the adjacency
 operator of a regular (multi)graph is applied through the (n, k) neighbor
 table, ``(A x)[i] = sum_j x[table[i, j]] + loops[i] * x[i]``, routed through
-the universal spmv dispatcher (:mod:`repro.kernels.spmv`): the Pallas kernel
-where it compiles, the pure-jnp reference elsewhere.
+the universal spmv dispatcher (:mod:`repro.kernels.spmv`).
 
 The batched solvers stream their (B, n, k) operand stacks through Lanczos in
 memory-bounded batch tiles (:data:`DEFAULT_BATCH_TILE_BYTES`), so a fault
 sweep or synthesis scoring pass at n ~ 10^5 never materializes B Lanczos
-bases at once; tiles are placed with
-:func:`repro.launch.mesh.shard_batch` so multi-device hosts split the batch.
+bases at once; on a multi-device host each tile is split over the devices
+(:func:`repro.launch.mesh.shard_batch` places it,
+:func:`repro.launch.mesh.over_batch` runs each device's slice).
 
 Relations used throughout (k-regular G):  rho_2 = k * mu_2 = k - lambda_2.
 """
@@ -170,8 +170,7 @@ def table_matvec(table: np.ndarray, loops: Optional[np.ndarray] = None,
                  ) -> Callable[[jnp.ndarray], jnp.ndarray]:
     """Adjacency operator from an (n, k) neighbor table.
 
-    Routed through the universal spmv dispatcher: the Pallas kernel where it
-    compiles, the pure-jnp gather-sum reference elsewhere.  ``backend``
+    Routed through the universal spmv dispatcher.  ``backend``
     (``"ref"`` / ``"pallas"`` / ``"pallas_interpret"``) is resolved once at
     closure creation; ``None`` follows :func:`repro.kernels.spmv.resolve_backend`.
     """
@@ -193,15 +192,19 @@ def _lanczos_scan(op: Callable, v0: jnp.ndarray, m: int
     v = v / jnp.linalg.norm(v)
     V0 = jnp.zeros((m + 1, n), dtype=jnp.float32).at[0].set(v)
 
+    # f32 products at default precision run as bf16 passes on the TPU, which
+    # the orthogonality of the basis (and so rho_2) cannot afford
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
+
     def body(carry, j):
         V, v, v_prev, beta_prev = carry
         w = op(v) - beta_prev * v_prev
-        alpha = jnp.dot(w, v)
+        alpha = dot(w, v)
         w = w - alpha * v
         mask = (jnp.arange(m + 1) <= j).astype(jnp.float32)
         for _ in range(2):  # two-pass full reorthogonalization
-            coeff = (V @ w) * mask
-            w = w - V.T @ coeff
+            coeff = dot(V, w) * mask
+            w = w - dot(V.T, coeff)
         beta = jnp.linalg.norm(w)
         ok = beta > 1e-7
         v_next = jnp.where(ok, w / jnp.where(ok, beta, 1.0), jnp.zeros_like(w))
@@ -430,10 +433,10 @@ def _batched_ritz_extremes(alphas: jnp.ndarray, betas: jnp.ndarray
     return lmin, lmax
 
 
-@functools.partial(jax.jit, static_argnames=("m", "backend"))
+@functools.partial(jax.jit, static_argnames=("m", "backend", "mesh"))
 def _lap_lanczos_batched(tables: jnp.ndarray, weights: jnp.ndarray,
                          degs: jnp.ndarray, v0s: jnp.ndarray, m: int,
-                         backend: Optional[str] = None
+                         backend: Optional[str] = None, mesh=None
                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """vmapped ones-deflated *Laplacian* Lanczos over B same-shape tables.
 
@@ -450,6 +453,8 @@ def _lap_lanczos_batched(tables: jnp.ndarray, weights: jnp.ndarray,
     ones-orthogonal eigenpair untouched.  A projection would let float32
     roundoff reintroduce the ones component, whose ghost 0 Ritz value poisons
     the *smallest* eigenvalue — exactly the one this path reports.
+    ``mesh`` (static) splits the batch over its devices
+    (:func:`repro.launch.mesh.over_batch`).
     """
     bk = KS.resolve_backend(backend)
 
@@ -463,7 +468,7 @@ def _lap_lanczos_batched(tables: jnp.ndarray, weights: jnp.ndarray,
         alphas, betas, _ = _lanczos_scan(op, v0, m)
         return alphas, betas
 
-    return jax.vmap(run)(tables, weights, degs, v0s)
+    return _mesh.over_batch(jax.vmap(run), mesh)(tables, weights, degs, v0s)
 
 
 def _tile_indices(lo: int, hi: int, tile: int) -> Tuple[np.ndarray, int]:
@@ -481,7 +486,8 @@ def rho2_laplacian_batched(tables: np.ndarray, weights: np.ndarray,
                            degs: np.ndarray, iters: int = 160,
                            seed: int = 0, *,
                            batch_chunk: Optional[int] = None,
-                           backend: Optional[str] = None) -> np.ndarray:
+                           backend: Optional[str] = None,
+                           devices: Optional[Sequence] = None) -> np.ndarray:
     """rho_2 for B (possibly irregular) graphs in one *streamed* Lanczos solve.
 
     Operands are stacked padded gather forms — ``tables`` (B, n, k) int32,
@@ -496,9 +502,9 @@ def rho2_laplacian_batched(tables: np.ndarray, weights: np.ndarray,
     The batch axis streams through the vmapped solve in memory-bounded tiles
     (``batch_chunk`` samples each; default from
     :data:`DEFAULT_BATCH_TILE_BYTES` — tier-1 sizes always fit one tile, so
-    results are identical to the unchunked solve).  Tiles are placed with
-    :func:`repro.launch.mesh.shard_batch`.  ``backend`` picks the spmv route
-    (default: kernel where it compiles, reference on CPU).
+    results are identical to the unchunked solve).  Each tile is split over
+    ``devices`` (default: every local device) when its size divides evenly.
+    ``backend`` picks the spmv route (default: the dispatcher's).
     """
     tables = np.asarray(tables)
     weights, degs = np.asarray(weights), np.asarray(degs)
@@ -509,26 +515,27 @@ def rho2_laplacian_batched(tables: np.ndarray, weights: np.ndarray,
     v0s = np.asarray(jax.random.normal(key, (B, n), dtype=jnp.float32))
     tile = _batch_tile(B, n, k, iters, batch_chunk)
     bk = KS.resolve_backend(backend)
+    mesh = _mesh.batch_mesh(tile, devices)
     alphas = np.empty((B, iters), dtype=np.float64)
     betas = np.empty((B, iters), dtype=np.float64)
     for lo in range(0, B, tile):
         idx, keep = _tile_indices(lo, min(lo + tile, B), tile)
         ops = _mesh.shard_batch(
-            jnp.asarray(tables[idx], dtype=jnp.int32),
+            mesh, jnp.asarray(tables[idx], dtype=jnp.int32),
             jnp.asarray(weights[idx], dtype=jnp.float32),
             jnp.asarray(degs[idx], dtype=jnp.float32),
             jnp.asarray(v0s[idx]))
-        a, b = _lap_lanczos_batched(*ops, iters, backend=bk)
+        a, b = _lap_lanczos_batched(*ops, iters, backend=bk, mesh=mesh)
         alphas[lo:lo + keep] = np.asarray(a, dtype=np.float64)[:keep]
         betas[lo:lo + keep] = np.asarray(b, dtype=np.float64)[:keep]
     lmin, _ = _batched_ritz_extremes(alphas, betas)
     return np.maximum(lmin, 0.0)
 
 
-@functools.partial(jax.jit, static_argnames=("m", "backend"))
+@functools.partial(jax.jit, static_argnames=("m", "backend", "mesh"))
 def _signed_lanczos_batched(table: jnp.ndarray, slot_signs: jnp.ndarray,
                             v0s: jnp.ndarray, m: int,
-                            backend: Optional[str] = None
+                            backend: Optional[str] = None, mesh=None
                             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """vmapped Lanczos on B *signed* adjacency operators sharing one table.
 
@@ -538,18 +545,22 @@ def _signed_lanczos_batched(table: jnp.ndarray, slot_signs: jnp.ndarray,
     start vectors.  The operator is ``(A_s x)[i] = sum_j s[i,j] x[table[i,j]]``
     — the Bilu–Linial signed adjacency in the padded gather-table contract,
     applied through the spmv dispatcher's ``signs=`` form.
-    No deflation: a signing destroys the trivial ±k eigenpairs.
+    No deflation: a signing destroys the trivial ±k eigenpairs.  ``mesh``
+    (static) splits the batch over its devices, the table replicated.
     """
     bk = KS.resolve_backend(backend)
 
-    def run(sg, v0):
-        def op(x):
-            return KS.spmv(x, table, signs=sg, backend=bk)
+    def solve(tab, sgs, vs):
+        def run(sg, v0):
+            def op(x):
+                return KS.spmv(x, tab, signs=sg, backend=bk)
 
-        alphas, betas, _ = _lanczos_scan(op, v0, m)
-        return alphas, betas
+            alphas, betas, _ = _lanczos_scan(op, v0, m)
+            return alphas, betas
 
-    return jax.vmap(run)(slot_signs, v0s)
+        return jax.vmap(run)(sgs, vs)
+
+    return _mesh.over_batch(solve, mesh, shared=1)(table, slot_signs, v0s)
 
 
 @obs.traced("spectral/signed_extremes_batched", phase="execute")
@@ -582,14 +593,16 @@ def signed_extremes_batched(table: np.ndarray, slot_signs: np.ndarray,
     tab = jnp.asarray(table, dtype=jnp.int32)
     tile = _batch_tile(B, n, k, iters, batch_chunk)
     bk = KS.resolve_backend(backend)
+    mesh = _mesh.batch_mesh(tile)
     alphas = np.empty((B, iters), dtype=np.float64)
     betas = np.empty((B, iters), dtype=np.float64)
     for lo in range(0, B, tile):
         idx, keep = _tile_indices(lo, min(lo + tile, B), tile)
         sg, v0 = _mesh.shard_batch(
-            jnp.asarray(slot_signs[idx], dtype=jnp.float32),
+            mesh, jnp.asarray(slot_signs[idx], dtype=jnp.float32),
             jnp.asarray(v0s[idx]))
-        a, b = _signed_lanczos_batched(tab, sg, v0, iters, backend=bk)
+        a, b = _signed_lanczos_batched(tab, sg, v0, iters, backend=bk,
+                                       mesh=mesh)
         alphas[lo:lo + keep] = np.asarray(a, dtype=np.float64)[:keep]
         betas[lo:lo + keep] = np.asarray(b, dtype=np.float64)[:keep]
     lmin, lmax = _batched_ritz_extremes(alphas, betas)

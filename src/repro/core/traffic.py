@@ -61,13 +61,12 @@ from typing import Dict, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro import obs
 
 from .graphs import Topology
 from .routing import (DEFAULT_SOURCE_CHUNK, RoutingResult, analyze_routing,
-                      reverse_slot_index)
+                      exact_counts, reverse_slot_index)
 from repro.kernels import spmv as KS
 
 try:                                   # optional: only the MCF LP bound
@@ -509,11 +508,10 @@ def ugal_link_loads(table: np.ndarray, routing: RoutingResult,
     return loads + lv, hops_min + hv, max(mh_min, mhv)
 
 
-@functools.partial(jax.jit, static_argnames=("Lmax", "slack", "backend"))
+@functools.partial(jax.jit, static_argnames=("Lmax", "slack"))
 def _ksp_loads_chunk(table: jnp.ndarray, nopad: jnp.ndarray,
                      dist: jnp.ndarray, demand: jnp.ndarray,
-                     Lmax: int, slack: int,
-                     backend: Optional[str] = None
+                     Lmax: int, slack: int
                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Near-minimal path ECMP loads for a (S, n) block — forward/backward
     walk DP over length layers.
@@ -528,17 +526,18 @@ def _ksp_loads_chunk(table: jnp.ndarray, nopad: jnp.ndarray,
     Backward: ``G[h](v)`` = downstream credit of being at v at step h;
     the load on slot (u, j) accumulates ``W[h][u] * G[h+1][table[u,j]]``.
     ``slack=0`` reproduces minimal ECMP exactly (equal weight per minimal
-    path — the same model as :func:`_ecmp_loads_chunk`).
+    path — the same model as :func:`_ecmp_loads_chunk`).  The walks are
+    float64 (traced inside :func:`repro.core.routing.exact_counts`), so every
+    spmv takes the ``ref`` backend: the Pallas kernel is float32-only.
     """
     obs.count("jit_trace/ksp")                   # trace-time increment
-    bk = KS.resolve_backend(backend)
     n, k = table.shape
 
     def one(dist_s, d_s):
         src = (dist_s == 0).astype(nopad.dtype)
 
         def fwd(W, _):
-            return KS.spmv(W, table, None, nopad, backend=bk), W
+            return KS.spmv(W, table, None, nopad, backend="ref"), W
 
         _, Ws = jax.lax.scan(fwd, src, None, length=Lmax + 1)
         dpos = jnp.maximum(dist_s, 0)
@@ -560,7 +559,7 @@ def _ksp_loads_chunk(table: jnp.ndarray, nopad: jnp.ndarray,
             loads = loads + nopad * wh[:, None] * g_next[table]
             admit = (dist_s >= 0) & (h >= dist_s) & (h <= dist_s + slack)
             g = jnp.where(admit, credit, 0.0) + \
-                KS.spmv(g_next, table, None, nopad, backend=bk)
+                KS.spmv(g_next, table, None, nopad, backend="ref")
             return (g, loads), None
 
         (_, loads_s), _ = jax.lax.scan(
@@ -574,15 +573,14 @@ def _ksp_loads_chunk(table: jnp.ndarray, nopad: jnp.ndarray,
 
 def ksp_link_loads(table: np.ndarray, routing: RoutingResult,
                    served: np.ndarray, *, slack: int = 1,
-                   chunk: int = DEFAULT_SOURCE_CHUNK,
-                   backend: Optional[str] = None
+                   chunk: int = DEFAULT_SOURCE_CHUNK
                    ) -> Tuple[np.ndarray, float, int]:
     """k-shortest-path non-minimal ECMP: equal split over every path of
     length <= ``dist(s, t) + slack``.
 
     Returns ``(loads (n, k) float64 — unscaled, hops_weighted, max_hops)``.
-    The DP runs in float64 (``enable_x64`` scope — walk counts overflow
-    float32 fast) with the source chunk re-sized so the per-source
+    The DP runs in float64 (:func:`repro.core.routing.exact_counts` — walk
+    counts overflow float32 fast) with the source chunk re-sized so the per-source
     (Lmax+1, n) walk stacks stay within a fixed byte budget.
     """
     if slack < 0:
@@ -600,14 +598,14 @@ def ksp_link_loads(table: np.ndarray, routing: RoutingResult,
     tab = jnp.asarray(table, dtype=jnp.int32)
     loads = np.zeros((n, k), dtype=np.float64)
     hops = 0.0
-    with enable_x64():
+    with exact_counts():
         npd = jnp.asarray(nopad, dtype=jnp.float64)
         for lo in range(0, dist.shape[0], inner):
             hi = min(lo + inner, dist.shape[0])
             lc, hc = _ksp_loads_chunk(
                 tab, npd, jnp.asarray(dist[lo:hi]),
                 jnp.asarray(served[lo:hi], dtype=jnp.float64),
-                Lmax=Lmax, slack=int(slack), backend=backend)
+                Lmax=Lmax, slack=int(slack))
             loads += np.asarray(lc, dtype=np.float64)
             hops += float(hc)
     return loads, hops, Lmax
@@ -648,7 +646,7 @@ def scheme_link_loads(table: np.ndarray, routing: RoutingResult,
                                chunk=chunk, backend=backend)
     if scheme == "ksp":
         return ksp_link_loads(table, routing, served, slack=slack,
-                              chunk=chunk, backend=backend)
+                              chunk=chunk)
     raise ValueError(f"unknown routing scheme {scheme!r} "
                      f"(known: {ROUTING_SCHEMES})")
 

@@ -10,9 +10,9 @@ from typing import Optional
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["activation_mesh", "constrain", "BATCH", "TP",
+__all__ = ["activation_mesh", "auto_axes", "constrain", "BATCH", "TP",
            "batch_axes", "pick_tp_dim"]
 
 # logical activation axes used by model code (resolved against the live mesh)
@@ -22,12 +22,20 @@ TP = "model"
 _ACT_MESH: Optional[Mesh] = None
 
 
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto``.  The model shards by GSPMD
+    propagation steered by ``constrain``, not by sharding in types, while
+    ``jax.make_mesh`` makes ``Explicit`` axes by default."""
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 class activation_mesh:
     """Context: model-internal ``constrain`` calls target this mesh.
     No-op (constraints vanish) when not entered — CPU unit tests unaffected."""
 
     def __init__(self, mesh: Optional[Mesh]):
-        self.mesh = mesh
+        self.mesh = None if mesh is None else auto_axes(mesh)
 
     def __enter__(self):
         global _ACT_MESH

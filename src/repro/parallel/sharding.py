@@ -33,7 +33,7 @@ from ..configs.base import ArchConfig, ShapeSpec
 from ..models import model as M
 
 from .act import (BATCH, TP, _axis_size, _div, activation_mesh,  # noqa: F401
-                  batch_axes, constrain, pick_tp_dim)
+                  auto_axes, batch_axes, constrain, pick_tp_dim)
 
 __all__ = ["batch_axes", "param_pspecs", "opt_pspecs", "batch_pspecs",
            "cache_pspecs", "to_shardings", "pick_tp_dim", "activation_mesh",
@@ -184,6 +184,7 @@ def cache_pspecs(cfg: ArchConfig, shape: ShapeSpec, mesh: Mesh) -> List[Dict]:
 
 
 def to_shardings(pspecs, mesh: Mesh):
+    mesh = auto_axes(mesh)
     return jax.tree.map(
         lambda p: NamedSharding(mesh, p),
         pspecs, is_leaf=lambda x: isinstance(x, P))

@@ -204,9 +204,9 @@ def test_adversarial_demand_backend_invariant(build):
     g = build()
     f = canonical_fiedler(g)
     D = demand_matrix("adversarial", g.n, fiedler=f)
+    r = analyze_routing(g)          # float64 sigma DP: always on ref
     results = {}
     for backend in ("ref", "pallas_interpret"):
-        r = analyze_routing(g, backend=backend)
         D_b = demand_matrix("adversarial", g.n, fiedler=canonical_fiedler(g))
         np.testing.assert_array_equal(D, D_b)
         t = evaluate_traffic(g, "adversarial", routing=r, fiedler=f,

@@ -6,8 +6,8 @@ loop-regularized irregular graphs.  Dispatch: backend resolution order and the
 ``use_backend`` override.  Routing: trace-count proofs — read from the
 ``spmv/pallas_trace`` counter of :mod:`repro.obs` — that the spectral /
 faults / synthesis / simulate engines actually apply their matvecs through
-the kernel under the kernel backend, and fall back cleanly to the reference
-path where Pallas cannot compile (CPU default).
+the kernel under the kernel backend, and stay on the reference path under
+``ref`` (the default).
 """
 import jax
 import jax.numpy as jnp
@@ -130,10 +130,9 @@ def test_spmv_dispatcher_and_matvec_agree():
 
 def test_backend_resolution_order(monkeypatch):
     monkeypatch.delenv("REPRO_SPMV_BACKEND", raising=False)
-    # auto: ref on CPU, pallas where it compiles
-    auto = "pallas" if KS.pallas_supported() else "ref"
-    assert KS.default_backend() == auto
-    assert KS.resolve_backend() == auto
+    # auto: ref on every platform (the kernel is opt-in; see spmv docstring)
+    assert KS.default_backend() == "ref"
+    assert KS.resolve_backend() == "ref"
     # env overrides auto
     monkeypatch.setenv("REPRO_SPMV_BACKEND", "pallas_interpret")
     assert KS.resolve_backend() == "pallas_interpret"
@@ -243,11 +242,27 @@ def test_simulate_routes_through_kernel():
 
 
 def test_routing_sigma_routes_through_kernel():
+    """The sigma DP is float64, which the float32-only kernel refuses: it
+    goes through the spmv dispatcher on ``ref`` whatever the ambient
+    backend, chosen by dtype in code."""
     from repro.core.routing import analyze_routing
 
     g = T.torus(3, 2)
-    assert _count_traces(lambda: analyze_routing(g)) > 0
+    with KS.use_backend(KS.kernel_backend()):
+        before = obs.counters()
+        analyze_routing(g)
+        delta = obs.counter_delta(before)
+    assert delta.get("spmv/pallas_trace", 0) == 0
+    assert delta.get("spmv/dispatch/ref", 0) > 0
     assert _count_ref(lambda: analyze_routing(g)) == 0
+
+
+def test_kernel_refuses_float64():
+    g = _random_regular(16, 4)
+    tab = jnp.asarray(g.gather_operands()[0], jnp.int32)
+    with jax.enable_x64(True):
+        with pytest.raises(TypeError, match="float64"):
+            KS.spmv_padded(jnp.zeros(16, jnp.float64), tab)
 
 
 def test_traffic_routes_through_kernel():
