@@ -372,15 +372,18 @@ def fault_sweep(topo: Topology, rates: Sequence[float] = (0.02, 0.05, 0.1, 0.2),
     rows: List[Dict] = []
     solves = 0
     for rate in rates:
-        scen = [make_scenario(topo, model, rate, seed=seed + 7919 * i,
-                              fiedler=fiedler) for i in range(B_samples)]
-        degraded = [apply_faults(topo, sc) for sc in scen]
-        tabs, ws, degs = stacked_operands(degraded, width=healthy_width)
+        with obs.span("faults/scenarios", rate=float(rate),
+                      samples=B_samples):
+            scen = [make_scenario(topo, model, rate, seed=seed + 7919 * i,
+                                  fiedler=fiedler) for i in range(B_samples)]
+            degraded = [apply_faults(topo, sc) for sc in scen]
+        with obs.span("faults/stack", samples=B_samples):
+            tabs, ws, degs = stacked_operands(degraded, width=healthy_width)
         rho2s = S.rho2_laplacian_batched(tabs, ws, degs, iters=iters, seed=seed)
         solves += 1
-        obs.count("faults/batched_solves")
-        comps = np.array([connected_component_count(d.n, d.edges)
-                          for d in degraded])
+        with obs.span("faults/components", samples=B_samples):
+            comps = np.array([connected_component_count(d.n, d.edges)
+                              for d in degraded])
         connected = comps == 1
         n_s = degraded[0].n
         kmax = max(float(d.degrees().max()) for d in degraded)

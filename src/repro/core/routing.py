@@ -383,26 +383,28 @@ def analyze_routing(topo: Union[Topology, Tuple[np.ndarray, int]],
         srcs = np.arange(n, dtype=np.int64)
     else:
         srcs = np.asarray(list(sources), dtype=np.int64)
-    obs.count("routing/bfs_sources", int(srcs.size))
-    dist = bfs_distances(table, srcs, chunk=chunk)
-    sigma = shortest_path_counts(table, dist, chunk=chunk)
-    finite = dist >= 0
-    offdiag = finite.copy()
-    offdiag[np.arange(srcs.size), srcs] = False   # drop s == t pairs
-    hops = dist[offdiag]
-    diameter = int(hops.max()) if hops.size else 0
-    hist = np.bincount(hops, minlength=diameter + 1) if hops.size else \
-        np.zeros(1, dtype=np.int64)
-    div = sigma[offdiag]
-    ecc = np.where(finite, dist, -1).max(axis=1)
+    with obs.span("routing/bfs", sources=int(srcs.size)):
+        dist = bfs_distances(table, srcs, chunk=chunk)
+    with obs.span("routing/sigma", sources=int(srcs.size)):
+        sigma = shortest_path_counts(table, dist, chunk=chunk)
+    with obs.span("routing/summary", sources=int(srcs.size)):
+        finite = dist >= 0
+        offdiag = finite.copy()
+        offdiag[np.arange(srcs.size), srcs] = False   # drop s == t pairs
+        hops = dist[offdiag]
+        diameter = int(hops.max()) if hops.size else 0
+        hist = np.bincount(hops, minlength=diameter + 1) if hops.size else \
+            np.zeros(1, dtype=np.int64)
+        div = sigma[offdiag]
+        ecc = np.where(finite, dist, -1).max(axis=1)
+        avg = float(hops.mean()) if hops.size else 0.0
     exact = bool(srcs.size == n)
-    avg = float(hops.mean()) if hops.size else 0.0
     if exact:
         ci = (avg, avg)
     else:
-        obs.count("routing/bootstrap_reps", int(bootstrap))
-        ci = _bootstrap_avg_hops_ci(dist, srcs, used_seed, bootstrap,
-                                    confidence)
+        with obs.span("routing/bootstrap", reps=int(bootstrap)):
+            ci = _bootstrap_avg_hops_ci(dist, srcs, used_seed, bootstrap,
+                                        confidence)
     return RoutingResult(
         name=name, n=n, sources=srcs, exact=exact,
         dist=dist, sigma=sigma, diameter=diameter,

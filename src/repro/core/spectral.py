@@ -256,22 +256,35 @@ def _tridiag_eigvals(alphas: np.ndarray, betas: np.ndarray) -> np.ndarray:
     return np.linalg.eigvalsh(T)
 
 
+def _lanczos_operands(n: int, seed: int,
+                      deflate_vectors: Optional[Sequence[np.ndarray]]
+                      ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
+    """Start vector and orthonormal deflation rows of a single-vector solve,
+    on the device and ready."""
+    with obs.span("lanczos/operands", n=n):
+        v0 = jax.random.normal(jax.random.PRNGKey(seed), (n,),
+                               dtype=jnp.float32)
+        deflate = None
+        if deflate_vectors:
+            D = np.stack([d / np.linalg.norm(d) for d in deflate_vectors])
+            # orthonormalize (tiny d x d Gram-Schmidt)
+            Q, _ = np.linalg.qr(D.T)
+            deflate = jnp.asarray(Q.T, dtype=jnp.float32)
+        return jax.block_until_ready((v0, deflate))
+
+
 def lanczos_extremes(matvec: Callable, n: int, m: int = 200, seed: int = 0,
                      deflate_vectors: Optional[Sequence[np.ndarray]] = None
                      ) -> Tuple[float, float]:
     """(lambda_max, lambda_min) of the (deflated) operator."""
     obs.count("lanczos/solves")
     obs.count("lanczos/iters", m)
-    key = jax.random.PRNGKey(seed)
-    v0 = jax.random.normal(key, (n,), dtype=jnp.float32)
-    deflate = None
-    if deflate_vectors:
-        D = np.stack([d / np.linalg.norm(d) for d in deflate_vectors])
-        # orthonormalize (tiny d x d Gram-Schmidt)
-        Q, _ = np.linalg.qr(D.T)
-        deflate = jnp.asarray(Q.T, dtype=jnp.float32)
-    alphas, betas = lanczos_tridiag(matvec, v0, m, deflate)
-    ev = _tridiag_eigvals(np.asarray(alphas), np.asarray(betas))
+    v0, deflate = _lanczos_operands(n, seed, deflate_vectors)
+    with obs.span("lanczos/solve", m=m):    # a fresh matvec retraces here
+        alphas, betas = lanczos_tridiag(matvec, v0, m, deflate)
+        alphas, betas = np.asarray(alphas), np.asarray(betas)
+    with obs.span("lanczos/ritz", m=m):
+        ev = _tridiag_eigvals(alphas, betas)
     return float(ev[-1]), float(ev[0])
 
 
@@ -286,13 +299,7 @@ def lanczos_top_ritz(matvec: Callable, n: int, m: int = 200, seed: int = 0,
     """
     obs.count("lanczos/solves")
     obs.count("lanczos/iters", m)
-    key = jax.random.PRNGKey(seed)
-    v0 = jax.random.normal(key, (n,), dtype=jnp.float32)
-    deflate = None
-    if deflate_vectors:
-        D = np.stack([d / np.linalg.norm(d) for d in deflate_vectors])
-        Q, _ = np.linalg.qr(D.T)
-        deflate = jnp.asarray(Q.T, dtype=jnp.float32)
+    v0, deflate = _lanczos_operands(n, seed, deflate_vectors)
     alphas, betas, V = _lanczos_with_basis(matvec, v0, m, deflate)
     alphas = np.asarray(alphas, dtype=np.float64)
     betas = np.asarray(betas, dtype=np.float64)[:-1]
@@ -511,8 +518,9 @@ def rho2_laplacian_batched(tables: np.ndarray, weights: np.ndarray,
     B, n, k = tables.shape
     obs.count("lanczos/solves", B)
     obs.count("lanczos/iters", B * iters)
-    key = jax.random.PRNGKey(seed)
-    v0s = np.asarray(jax.random.normal(key, (B, n), dtype=jnp.float32))
+    with obs.span("lanczos/operands", batch=B, n=n):    # start vectors
+        key = jax.random.PRNGKey(seed)
+        v0s = np.asarray(jax.random.normal(key, (B, n), dtype=jnp.float32))
     tile = _batch_tile(B, n, k, iters, batch_chunk)
     bk = KS.resolve_backend(backend)
     mesh = _mesh.batch_mesh(tile, devices)
@@ -520,15 +528,18 @@ def rho2_laplacian_batched(tables: np.ndarray, weights: np.ndarray,
     betas = np.empty((B, iters), dtype=np.float64)
     for lo in range(0, B, tile):
         idx, keep = _tile_indices(lo, min(lo + tile, B), tile)
-        ops = _mesh.shard_batch(
-            mesh, jnp.asarray(tables[idx], dtype=jnp.int32),
-            jnp.asarray(weights[idx], dtype=jnp.float32),
-            jnp.asarray(degs[idx], dtype=jnp.float32),
-            jnp.asarray(v0s[idx]))
-        a, b = _lap_lanczos_batched(*ops, iters, backend=bk, mesh=mesh)
-        alphas[lo:lo + keep] = np.asarray(a, dtype=np.float64)[:keep]
-        betas[lo:lo + keep] = np.asarray(b, dtype=np.float64)[:keep]
-    lmin, _ = _batched_ritz_extremes(alphas, betas)
+        with obs.span("lanczos/operands", batch=tile, n=n):   # the tile
+            ops = jax.block_until_ready(_mesh.shard_batch(
+                mesh, jnp.asarray(tables[idx], dtype=jnp.int32),
+                jnp.asarray(weights[idx], dtype=jnp.float32),
+                jnp.asarray(degs[idx], dtype=jnp.float32),
+                jnp.asarray(v0s[idx])))
+        with obs.span("lanczos/solve", batch=tile, m=iters):
+            a, b = _lap_lanczos_batched(*ops, iters, backend=bk, mesh=mesh)
+            alphas[lo:lo + keep] = np.asarray(a, dtype=np.float64)[:keep]
+            betas[lo:lo + keep] = np.asarray(b, dtype=np.float64)[:keep]
+    with obs.span("lanczos/ritz", batch=B, m=iters):
+        lmin, _ = _batched_ritz_extremes(alphas, betas)
     return np.maximum(lmin, 0.0)
 
 

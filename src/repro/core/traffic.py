@@ -881,9 +881,10 @@ def evaluate_traffic(topo: Union[Topology, Tuple[np.ndarray, int]],
     served[np.arange(S), srcs] = 0.0
     total = float(served.sum())
     dropped = float(D.sum() - D[np.arange(S), srcs].sum() - total)
-    loads, hops_weighted, _ = scheme_link_loads(
-        table, routing, served, scheme, slack=slack, chunk=chunk,
-        backend=backend)
+    with obs.span("traffic/loads", scheme=scheme, sources=S):
+        loads, hops_weighted, _ = scheme_link_loads(
+            table, routing, served, scheme, slack=slack, chunk=chunk,
+            backend=backend)
     load_sum = float(loads.sum())
     # conservation holds per source row, so check it *before* the n/S scale
     conservation = abs(load_sum - hops_weighted) / max(hops_weighted, 1e-12)
@@ -891,8 +892,9 @@ def evaluate_traffic(topo: Union[Topology, Tuple[np.ndarray, int]],
     max_load = float(loads.max()) if loads.size else 0.0
     ucb = max_load
     if not routing.exact and scheme == "minimal" and max_load > 0:
-        ucb = _max_link_load_ucb(table, routing, served, loads,
-                                 chunk=chunk, backend=backend)
+        with obs.span("traffic/ucb", sources=S):
+            ucb = _max_link_load_ucb(table, routing, served, loads,
+                                     chunk=chunk, backend=backend)
     sat_denom = max_load if routing.exact else ucb
     loaded = loads[loads > 0]
     return TrafficResult(

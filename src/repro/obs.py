@@ -26,17 +26,26 @@ faults, synthesis, simulate, workloads, the spmv kernel dispatcher):
     backend (the trace-time backend-resolution invariant of the survey).
   - ``lanczos/solves`` / ``lanczos/iters`` /
     ``lanczos/breakdown_truncations`` — host-side Lanczos accounting.
-  - ``routing/bfs_sources`` / ``routing/bootstrap_reps`` — sampled-routing
-    effort accounting.
   - ``survey/lanczos_groups`` / ``survey/lanczos_grouped_instances`` — the
     PR-1 same-shape batching decisions.
 
+* **JAX's compile pipeline** — while spans are enabled, each of JAX's
+  ``jax.monitoring`` time spans for tracing a function to a jaxpr, lowering
+  it to MLIR and compiling it for the backend is recorded as a span
+  (``jax/trace``, ``jax/lower``, ``jax/compile``, tagged ``fun=``) nested
+  under the span open at the time, so a retrace says which step it
+  happened in.  And every enabled span also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so a ``jax.profiler``
+  session shows the spans on the host line of the device trace's clock.
+  Both need ``jax`` to be imported already; this module never imports it.
 * **Telemetry** — the per-round simulator arrays live in
   :class:`repro.core.simulate.RoundTelemetry` (``run_schedule(telemetry=
   True)``); this module only carries the span/counter side.
 
 Everything here is stdlib-only (``time``/``resource``/``json``/``threading``)
-so ``tools/``-style consumers can import it with no numpy/jax installed.
+so ``tools/``-style consumers can import it with no numpy/jax installed;
+the JAX hooks above look ``jax`` up in ``sys.modules`` and stay idle
+without it.
 RSS figures use ``getrusage(RUSAGE_SELF).ru_maxrss`` (KiB on Linux): a
 *high-water* mark, so a span's ``rss_delta_kb`` reports how much the process
 peak grew during the span (0 for work below the current peak), not live heap.
@@ -48,6 +57,7 @@ import dataclasses
 import functools
 import json
 import pathlib
+import sys
 import threading
 import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Union
@@ -136,6 +146,7 @@ def enabled() -> bool:
 def enable() -> None:
     """Start recording spans (counters are always on regardless)."""
     global _ENABLED
+    _jax_annotation()
     _ENABLED = True
 
 
@@ -157,9 +168,52 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+#: JAX's compile-pipeline ``jax.monitoring`` time spans -> span names
+JAX_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax/lower",
+    "/jax/core/compile/backend_compile_duration": "jax/compile",
+}
+_ANNOTATION: Optional[type] = None      # jax.profiler.TraceAnnotation, once hooked
+
+
+def _jax_annotation() -> Optional[type]:
+    """``jax.profiler.TraceAnnotation`` if ``jax`` is imported, else None.
+
+    The first call that finds ``jax`` also registers the listener that
+    records JAX's compile pipeline as spans (:data:`JAX_SPANS`)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        jax = sys.modules.get("jax")
+        if getattr(jax, "profiler", None) is None or \
+                getattr(jax, "monitoring", None) is None:
+            return None
+        with _LOCK:
+            if _ANNOTATION is None:
+                jax.monitoring.register_event_time_span_listener(
+                    _on_jax_time_span)
+                _ANNOTATION = jax.profiler.TraceAnnotation
+    return _ANNOTATION
+
+
+def _on_jax_time_span(event: str, start: float, end: float,
+                      **kwargs: Any) -> None:
+    """Record one JAX compile step (``time.time()`` start and end) as a span
+    on this module's clock, one level below the innermost open span."""
+    if not _ENABLED or event not in JAX_SPANS:
+        return
+    shift = time.perf_counter() - time.time()
+    depth = len(getattr(_TLS, "stack", ()))
+    ev = dict(name=JAX_SPANS[event], ph="X", cat="jax",
+              ts=(start + shift - _T0) * 1e6, dur=(end - start) * 1e6,
+              pid=1, tid=threading.get_ident() & 0xFFFF,
+              args=dict(fun=str(kwargs.get("fun_name", "")), depth=depth))
+    with _LOCK:
+        _EVENTS.append(ev)
+
 
 class _Span:
-    __slots__ = ("name", "tags", "_t_start", "_rss0", "_depth")
+    __slots__ = ("name", "tags", "_t_start", "_rss0", "_depth", "_ann")
 
     def __init__(self, name: str, tags: Dict[str, Any]):
         self.name = name
@@ -172,11 +226,17 @@ class _Span:
         self._depth = len(stack)
         stack.append(self)
         self._rss0 = peak_rss_kb()
+        ann = _jax_annotation()
+        self._ann = None if ann is None else ann(self.name)
+        if self._ann is not None:       # last, so both clocks bracket the same work
+            self._ann.__enter__()
         self._t_start = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t_end = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         rss1 = peak_rss_kb()
         stack = _TLS.stack
         if stack and stack[-1] is self:
@@ -241,6 +301,7 @@ def tracing(path: Optional[Union[str, pathlib.Path]] = None):
     prev = _ENABLED
     if not prev:
         reset_spans()
+    _jax_annotation()
     _ENABLED = True
     try:
         yield

@@ -4,7 +4,9 @@ Four layers of coverage:
 
 * ``repro.obs`` primitives — counters + deltas, span nesting and the
   disabled-path no-op, ``tracing()`` buffer semantics, Chrome-trace JSON,
-  ``render_tree``, and the phase interval-union of ``metrics_report``.
+  ``render_tree``, and the phase interval-union of ``metrics_report``; spans
+  on a ``jax.profiler`` trace's host plane, JAX's trace / lower / compile
+  steps recorded as spans, and an import with no ``jax``.
 * Per-round simulator telemetry — ``RoundTelemetry`` arrays from
   ``run_schedule(telemetry=True)`` / ``Analysis.simulate(telemetry=True)``:
   the max over rounds of the per-unit-payload link load must equal the
@@ -20,17 +22,25 @@ Four layers of coverage:
   monkey-patch call counting.
 """
 import json
+import os
+import pathlib
+import subprocess
+import sys
+import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import obs
+from repro.api import survey
 from repro.api.analysis import Analysis
 from repro.api.registry import build
-from repro.api.survey import survey
 from repro.core import topologies as T
 from repro.core.simulate import RoundTelemetry, compile_schedule, run_schedule
+
+SRC = pathlib.Path(__file__).resolve().parents[1] / "src"
 
 
 # --------------------------------------------------------------------------
@@ -167,6 +177,80 @@ def test_metrics_report_phases_interval_union():
 
 def test_peak_rss_is_positive_high_water():
     assert obs.peak_rss_kb() > 0
+
+
+# --------------------------------------------------------------------------
+# spans on the device trace's clock, and JAX's compile pipeline as spans
+# --------------------------------------------------------------------------
+
+def test_span_appears_on_the_profiler_host_plane(tmp_path):
+    from jax.profiler import ProfileData
+
+    name = "test/annotated_span"
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.tracing():
+            with obs.span(name, step=1):
+                time.sleep(0.02)
+            ev = [e for e in obs.trace_events() if e["name"] == name]
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    host = [e for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host") for line in plane.lines
+            for e in line.events if e.name == name]
+    assert len(ev) == 1 and len(host) == 1
+    assert abs(host[0].duration_ns / 1e6 - ev[0]["dur"] / 1e3) < 1.0
+
+
+def test_fresh_jit_records_trace_lower_and_compile_spans():
+    x = jnp.arange(7.0)
+    with obs.tracing():
+        obs.reset_spans()
+        with obs.span("outer"):
+            jax.jit(lambda v: v * 3.0 + 2.0)(x).block_until_ready()
+        evs = obs.trace_events()
+    jax_evs = [e for e in evs if e["name"].startswith("jax/")]
+    assert {"jax/trace", "jax/lower", "jax/compile"} <= {
+        e["name"] for e in jax_evs}
+    outer = next(e for e in evs if e["name"] == "outer")
+    for e in jax_evs:
+        assert e["args"]["depth"] == 1 and e["args"]["fun"]
+        # nested in the span open at the time, on its clock
+        assert outer["ts"] - 1e3 <= e["ts"]
+        assert e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1e3
+    assert any(e["args"]["fun"] == "<lambda>" for e in jax_evs
+               if e["name"] == "jax/trace")
+    obs.disable()
+    obs.reset_spans()
+    jax.jit(lambda v: v * 5.0 - 1.0)(x).block_until_ready()
+    assert obs.trace_events() == []        # disabled: the listener is idle
+
+
+def test_jit_cache_hit_records_no_trace_span():
+    f = jax.jit(lambda v: v * 7.0 + 1.0)
+    x = jnp.arange(5.0)
+    f(x).block_until_ready()               # traced and compiled here
+    with obs.tracing():
+        obs.reset_spans()
+        with obs.span("hit"):
+            f(x).block_until_ready()
+        names = [e["name"] for e in obs.trace_events()]
+    assert names == ["hit"]
+
+
+def test_obs_imports_and_traces_without_jax():
+    code = ("import sys; sys.modules['jax'] = None\n"
+            "from repro import obs\n"
+            "with obs.tracing():\n"
+            "    with obs.span('a', n=1):\n"
+            "        pass\n"
+            "assert [e['name'] for e in obs.trace_events()] == ['a']\n"
+            "assert 'jax' not in [m for m in sys.modules if sys.modules[m]]\n")
+    env = dict(os.environ, PYTHONPATH=str(SRC))
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
 
 
 # --------------------------------------------------------------------------
