@@ -443,7 +443,8 @@ def _batched_ritz_extremes(alphas: jnp.ndarray, betas: jnp.ndarray
 @functools.partial(jax.jit, static_argnames=("m", "backend", "mesh"))
 def _lap_lanczos_batched(tables: jnp.ndarray, weights: jnp.ndarray,
                          degs: jnp.ndarray, v0s: jnp.ndarray, m: int,
-                         backend: Optional[str] = None, mesh=None
+                         backend: Optional[str] = None, mesh=None,
+                         counts: Optional[jnp.ndarray] = None
                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """vmapped ones-deflated *Laplacian* Lanczos over B same-shape tables.
 
@@ -453,6 +454,13 @@ def _lap_lanczos_batched(tables: jnp.ndarray, weights: jnp.ndarray,
     holds per-vertex degrees *including* signed self-loop weights, which makes
     ``deg * x - (gather + w * x)`` exactly L x (loops cancel).
 
+    ``tables`` is either one (B, n, k) table a sample, or one (n, k) table
+    shared by the batch with per-sample slot ``counts`` (B, n, k) — the
+    operands :func:`_shared_table` derives.  The rank of ``tables`` picks the
+    gather at trace time: per-sample tables gather B·n·k scalars a step; the
+    shared table, vmapped with the batch on its minor axis, gathers n·k rows
+    of B (XLA lays the (B, n) vector out batch-minor for it).
+
     Deflation of the trivial 0 eigenpair (ones) is done by a rank-one SHIFT,
     not a projection: ``L + c * ones ones^T / n`` moves the ones eigenvalue to
     ``c = max_deg + 2 > rho2`` (Fiedler: rho2 <= vertex connectivity <=
@@ -461,21 +469,67 @@ def _lap_lanczos_batched(tables: jnp.ndarray, weights: jnp.ndarray,
     roundoff reintroduce the ones component, whose ghost 0 Ritz value poisons
     the *smallest* eigenvalue — exactly the one this path reports.
     ``mesh`` (static) splits the batch over its devices
-    (:func:`repro.launch.mesh.over_batch`).
+    (:func:`repro.launch.mesh.over_batch`), a shared table replicated.
     """
     bk = KS.resolve_backend(backend)
 
-    def run(tab, lw, deg, v0):
+    def run(tab, cnt, lw, deg, v0):
         c = jnp.max(deg) + 2.0
 
         def op(x):
-            lx = deg * x - KS.spmv(x, tab, lw, backend=bk)
+            lx = deg * x - KS.spmv(x, tab, lw, signs=cnt, backend=bk)
             return lx + c * jnp.mean(x)
 
         alphas, betas, _ = _lanczos_scan(op, v0, m)
         return alphas, betas
 
-    return _mesh.over_batch(jax.vmap(run), mesh)(tables, weights, degs, v0s)
+    if tables.ndim == 2:
+        solve = jax.vmap(run, in_axes=(None, 0, 0, 0, 0))
+        return _mesh.over_batch(solve, mesh, shared=1)(
+            tables, counts, weights, degs, v0s)
+    solve = jax.vmap(lambda tab, lw, deg, v0: run(tab, None, lw, deg, v0))
+    return _mesh.over_batch(solve, mesh)(tables, weights, degs, v0s)
+
+
+def _shared_table(tables: np.ndarray, weights: np.ndarray
+                  ) -> Tuple[int, Optional[Tuple[np.ndarray, np.ndarray,
+                                                 np.ndarray]]]:
+    """One (n, k) table for a (B, n, k) stack whose samples differ only in
+    which of each vertex's neighbours they keep.
+
+    Returns the largest union width, over vertices, of the samples' entries
+    other than the vertex itself, and, where it is at most k, the operands
+    ``(U, C, w2)`` of the same operator: ``U`` (n, k) int32 holds each
+    vertex's union, padded with the vertex itself; ``C`` (B, n, k) float32
+    counts how often ``U[i, j]`` appears in ``tables[b, i]`` (0 in pad
+    slots); ``w2`` (B, n) float32 adds each sample's self entries (pads and
+    loops) to ``weights``.  So ``sum_j C[b,i,j] x[U[i,j]] + w2[b,i] x[i]``
+    is ``sum_j x[tables[b,i,j]] + weights[b,i] x[i]`` exactly.  Link faults
+    only delete edges, so every stack of them shares its healthy table.
+    """
+    B, n, k = tables.shape
+    ids = np.arange(n, dtype=np.int32)
+    ent = np.sort(tables.transpose(1, 0, 2).reshape(n, B * k), axis=1)
+    new = np.ones(ent.shape, dtype=bool)
+    new[:, 1:] = ent[:, 1:] != ent[:, :-1]
+    new &= ent != ids[:, None]
+    width = int(new.sum(axis=1).max(initial=0))
+    if width > k:
+        return width, None
+    # each row's distinct entries first; n sorts last and marks the pads
+    U = np.sort(np.where(new, ent, n), axis=1)[:, :k].astype(np.int32)
+    U = np.where(U == n, ids[:, None], U)
+    # count slot plane by slot plane, vertices innermost: (B, k, n)
+    planes = np.ascontiguousarray(tables.transpose(0, 2, 1))
+    UT = np.where(U == ids[:, None], -1, U).T.copy()     # pads match nothing
+    C = np.zeros((B, k, n), dtype=np.int16)
+    selfs = np.zeros((B, n), dtype=np.int16)
+    for j in range(k):
+        C += planes[:, j, None, :] == UT
+        selfs += planes[:, j, :] == ids
+    return width, (U, np.ascontiguousarray(C.transpose(0, 2, 1),
+                                           dtype=np.float32),
+                   (weights + selfs).astype(np.float32))
 
 
 def _tile_indices(lo: int, hi: int, tile: int) -> Tuple[np.ndarray, int]:
@@ -511,7 +565,10 @@ def rho2_laplacian_batched(tables: np.ndarray, weights: np.ndarray,
     :data:`DEFAULT_BATCH_TILE_BYTES` — tier-1 sizes always fit one tile, so
     results are identical to the unchunked solve).  Each tile is split over
     ``devices`` (default: every local device) when its size divides evenly.
-    ``backend`` picks the spmv route (default: the dispatcher's).
+    ``backend`` picks the spmv route (default: the dispatcher's).  On
+    ``ref``, a tile whose samples fit one shared table (:func:`_shared_table`:
+    every link-fault stack) is solved through it, gathering rows of the
+    batch; any other tile keeps one table a sample.
     """
     tables = np.asarray(tables)
     weights, degs = np.asarray(weights), np.asarray(degs)
@@ -528,14 +585,29 @@ def rho2_laplacian_batched(tables: np.ndarray, weights: np.ndarray,
     betas = np.empty((B, iters), dtype=np.float64)
     for lo in range(0, B, tile):
         idx, keep = _tile_indices(lo, min(lo + tile, B), tile)
-        with obs.span("lanczos/operands", batch=tile, n=n):   # the tile
-            ops = jax.block_until_ready(_mesh.shard_batch(
-                mesh, jnp.asarray(tables[idx], dtype=jnp.int32),
-                jnp.asarray(weights[idx], dtype=jnp.float32),
+        with obs.span("lanczos/operands", batch=tile, n=n) as sp:   # the tile
+            # the row gather is XLA's; the kernel keeps one table a sample
+            width, shared = _shared_table(tables[idx], weights[idx]) \
+                if bk == "ref" else (None, None)
+            sp.tag(shared_width=width)
+            if shared is None:
+                obs.count("lanczos/per_sample_tiles")
+                tab = _mesh.shard_batch(mesh, jnp.asarray(tables[idx],
+                                                          dtype=jnp.int32))
+                counts, w = None, weights[idx]
+            else:
+                obs.count("lanczos/shared_table_tiles")
+                U, C, w = shared
+                tab = jnp.asarray(U)
+                counts = _mesh.shard_batch(mesh, jnp.asarray(C))
+            w, d, v0 = _mesh.shard_batch(
+                mesh, jnp.asarray(w, dtype=jnp.float32),
                 jnp.asarray(degs[idx], dtype=jnp.float32),
-                jnp.asarray(v0s[idx])))
+                jnp.asarray(v0s[idx]))
+            jax.block_until_ready((tab, counts, w, d, v0))
         with obs.span("lanczos/solve", batch=tile, m=iters):
-            a, b = _lap_lanczos_batched(*ops, iters, backend=bk, mesh=mesh)
+            a, b = _lap_lanczos_batched(tab, w, d, v0, iters, backend=bk,
+                                        mesh=mesh, counts=counts)
             alphas[lo:lo + keep] = np.asarray(a, dtype=np.float64)[:keep]
             betas[lo:lo + keep] = np.asarray(b, dtype=np.float64)[:keep]
     with obs.span("lanczos/ritz", batch=B, m=iters):

@@ -7,6 +7,12 @@ padded gather-table contract (``graphs.Topology.gather_operands``):
 
 with ``signs`` defaulting to all-ones (plain adjacency; the signed form is
 the Bilu–Linial operator of the synthesis subsystem) and ``loops`` to zero.
+``signs`` is any per-slot weight: the batched Laplacian Lanczos of a fault
+sweep passes one (n, k) table shared by the whole batch, each vertex's
+neighbours over every sample, with per-sample slot counts as ``signs``
+(:func:`repro.core.spectral._shared_table`).  Vmapped over the batch with
+the table unbatched, :func:`spmv_ref`'s gather then takes n·k rows of B
+values, the batch on lanes, where B (n, k) tables took B·n·k scalars.
 This module is the single dispatch point for that operator:
 
 * :func:`spmv_ref`    — pure-jnp reference (XLA gather + sum), any backend;

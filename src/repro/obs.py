@@ -26,6 +26,8 @@ faults, synthesis, simulate, workloads, the spmv kernel dispatcher):
     backend (the trace-time backend-resolution invariant of the survey).
   - ``lanczos/solves`` / ``lanczos/iters`` /
     ``lanczos/breakdown_truncations`` — host-side Lanczos accounting.
+  - ``lanczos/shared_table_tiles`` / ``lanczos/per_sample_tiles`` — batched
+    Laplacian tiles solved through one shared table or a table a sample.
   - ``survey/lanczos_groups`` / ``survey/lanczos_grouped_instances`` — the
     PR-1 same-shape batching decisions.
 
@@ -165,6 +167,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def tag(self, **tags: Any) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -251,6 +256,10 @@ class _Span:
         with _LOCK:
             _EVENTS.append(ev)
         return False
+
+    def tag(self, **tags: Any) -> None:
+        """Add tags known only once the span's work has run."""
+        self.tags = {**self.tags, **tags}
 
 
 def span(name: str, **tags: Any):

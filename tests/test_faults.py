@@ -1,11 +1,18 @@
 """Fault models + batched degraded-spectral sweeps (repro.core.faults)."""
+import json
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.api import Analysis, survey
 from repro.core import faults as F
 from repro.core import spectral as S
 from repro.core import topologies as T
+from repro.core.graphs import Topology
 
 
 # --------------------------------------------------------------------------
@@ -118,6 +125,145 @@ def test_batched_rho2_flags_disconnection():
     tabs, ws, degs = F.stacked_operands([d])
     got = S.rho2_laplacian_batched(tabs, ws, degs, iters=64, seed=0)
     assert got[0] < 1e-4
+
+
+# --------------------------------------------------------------------------
+# one shared neighbour table for a stack: the row-gather path
+# --------------------------------------------------------------------------
+
+def _link_stack():
+    g = T.torus(8, 2)
+    return [F.apply_faults(g, F.random_link_faults(g, 0.15, seed=i))
+            for i in range(6)]
+
+
+def _looped_cycle_stack():
+    """A cycle with a loop-weighted vertex, links cut at random."""
+    n = 12
+    loops = np.zeros(n)
+    loops[3] = 2.0
+    g = Topology("looped-cycle", n, np.array([(i, (i + 1) % n)
+                                              for i in range(n)]), loops=loops)
+    return [F.apply_faults(g, F.random_link_faults(g, 0.2, seed=i))
+            for i in range(5)]
+
+
+def _multigraph_stack():
+    """Repeated neighbours: one edge doubled and one tripled, so a table row
+    holds one vertex two or three times."""
+    n = 10
+    edges = [(i, (i + 1) % n) for i in range(n)] + [(0, 1), (4, 5), (4, 5)]
+    g = Topology("multi-cycle", n, np.array(edges))
+    return [F.apply_faults(g, F.random_link_faults(g, 0.2, seed=i))
+            for i in range(5)]
+
+
+def _node_stack():
+    g = T.torus(8, 2)
+    return [F.apply_faults(g, F.random_node_faults(g, 0.1, seed=i))
+            for i in range(4)]
+
+
+def _wide_stack():
+    """Samples of one order with unrelated neighbourhoods: the union of a
+    vertex's neighbours over the stack outgrows the table's width."""
+    g = T.cycle(16)
+    perms = [np.random.default_rng(i).permutation(16) for i in range(4)]
+    return [Topology(f"relabelled-{i}", 16, p[g.edges])
+            for i, p in enumerate(perms)]
+
+
+SHARED_STACKS = {"torus-links": _link_stack,
+                 "looped-cycle": _looped_cycle_stack,
+                 "multigraph": _multigraph_stack}
+
+
+@pytest.mark.parametrize("stack", sorted(SHARED_STACKS))
+def test_shared_table_applies_the_per_sample_operator(stack):
+    degraded = SHARED_STACKS[stack]()
+    tabs, ws, degs = F.stacked_operands(degraded)
+    width, shared = S._shared_table(tabs, ws)
+    assert shared is not None and width <= tabs.shape[2]
+    U, C, w2 = shared
+    rng = np.random.default_rng(1)
+    for b, d in enumerate(degraded):
+        x = rng.normal(size=d.n)
+        per_sample = x[tabs[b]].sum(axis=1) + ws[b] * x
+        one_table = (C[b] * x[U]).sum(axis=1) + w2[b] * x
+        assert np.abs(one_table - per_sample).max() < 1e-6
+        lx = degs[b] * x - one_table
+        assert np.abs(lx - d.laplacian() @ x).max() < 1e-6
+
+
+@pytest.mark.parametrize("stack", sorted(SHARED_STACKS))
+def test_shared_table_solve_matches_dense_oracle(stack):
+    degraded = SHARED_STACKS[stack]()
+    tabs, ws, degs = F.stacked_operands(degraded)
+    got = S.rho2_laplacian_batched(tabs, ws, degs, iters=40, seed=0,
+                                   backend="ref")
+    want = np.array([max(S.laplacian_spectrum(d)[1], 0.0) for d in degraded])
+    assert np.abs(got - want).max() < 1e-3
+
+
+@pytest.mark.parametrize("stack,shared", [("torus-links", True),
+                                          ("node-faults", False),
+                                          ("wider-than-k", False)])
+def test_batched_solve_counts_its_gather(stack, shared):
+    make = dict(SHARED_STACKS, **{"node-faults": _node_stack,
+                                  "wider-than-k": _wide_stack})[stack]
+    tabs, ws, degs = F.stacked_operands(make())
+    before = obs.counters("lanczos/")
+    S.rho2_laplacian_batched(tabs, ws, degs, iters=20, seed=0, backend="ref")
+    delta = obs.counter_delta(before, "lanczos/")
+    assert delta.get("lanczos/shared_table_tiles", 0) == int(shared)
+    assert delta.get("lanczos/per_sample_tiles", 0) == int(not shared)
+
+
+def test_link_sweep_second_rate_does_not_retrace():
+    g = T.torus(8, 2)
+    F.fault_sweep(g, rates=(0.05,), samples=8, seed=0, iters=30)
+    before = obs.counters()
+    F.fault_sweep(g, rates=(0.25,), samples=8, seed=1, iters=30)
+    delta = obs.counter_delta(before)
+    assert "jit_trace/lanczos_scan" not in delta
+    assert delta.get("lanczos/shared_table_tiles", 0) == 1
+
+
+SHARDED = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json
+import jax
+import numpy as np
+from repro import obs
+from repro.core import faults as F, spectral as S, topologies as T
+
+g = T.torus(8, 2)
+degraded = [F.apply_faults(g, F.random_link_faults(g, 0.15, seed=i))
+            for i in range(8)]
+tabs, ws, degs = F.stacked_operands(degraded)
+before = obs.counters("lanczos/")
+sharded = S.rho2_laplacian_batched(tabs, ws, degs, iters=60, backend="ref")
+single = S.rho2_laplacian_batched(tabs, ws, degs, iters=60, backend="ref",
+                                  devices=jax.devices()[:1])
+print(json.dumps(dict(devices=len(jax.devices()),
+                      diff=float(np.abs(sharded - single).max()),
+                      counters=obs.counter_delta(before, "lanczos/"))))
+"""
+
+
+def test_sharded_shared_table_solve_matches_one_device():
+    """Four host devices: the replicated table and the batch split 2 a
+    device give the one-device answer."""
+    env = dict(os.environ, PYTHONPATH=os.path.abspath("src"),
+               JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", SHARDED], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    assert res["devices"] == 4
+    assert res["counters"].get("lanczos/shared_table_tiles") == 2
+    assert res["diff"] <= 1e-5, res
 
 
 def test_connected_component_count_matches_networkx():

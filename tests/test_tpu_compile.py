@@ -106,3 +106,18 @@ def test_lap_lanczos_tile_compiles_on_tpu_default_backend(which, one_chip,
         sds((B, n), F32), 160, backend=backend).compile()
     if backend == "pallas":
         assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("B,n,k,m", [(32, 4096, 6, 200), (4, 65536, 32, 160)])
+def test_lap_lanczos_shared_table_compiles(B, n, k, m, one_chip):
+    """The batched Laplacian Lanczos through one (n, k) table shared by the
+    batch, with per-sample slot counts, gathers rows of the batch: the fault
+    sweep's tile, and a datacenter-size one."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = S._lap_lanczos_batched.lower(
+        sds((n, k), I32), sds((B, n), F32), sds((B, n), F32),
+        sds((B, n), F32), m, backend="ref",
+        counts=sds((B, n, k), F32)).compile()
+    assert f"slice_sizes={{{B},1}}" in compiled.as_text()   # rows of B
