@@ -480,7 +480,8 @@ class Analysis:
         ``workload=`` (spec string / :class:`~repro.core.workloads.CommPlan`)
         executes the full training-step plan on the first
         ``workload_samples`` degraded samples per rate, appending
-        ``workload_step_mean/max`` and ``workload_dropped_frac_mean``."""
+        ``workload_step_mean/max``, ``workload_dropped_frac_mean`` and
+        ``workload_phase_seconds_mean`` (phase name -> mean seconds)."""
         fiedler = self.fiedler if model == "attack_spectral" else None
         return F.fault_sweep(
             self.topo, rates=rates, model=model, samples=samples,

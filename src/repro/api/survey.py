@@ -140,7 +140,8 @@ SIM_COLUMNS = [
 #: the canonical workload spec, total simulated step time and its compute
 #: term (ms), per-phase-family link time (``comm_dp_ms`` gradient
 #: all-reduce, ``comm_tp_ms`` tensor-parallel all-gather/reduce-scatter,
-#: ``comm_moe_ms`` expert all-to-all, ``comm_total_ms`` their sum),
+#: ``comm_moe_ms`` expert all-to-all, ``comm_pp_ms`` pipeline send/receive,
+#: ``comm_total_ms`` their sum),
 #: the exposed-communication fraction of the step ((step - compute)/step,
 #: after DP/backward overlap), and the fraction of plan demand dropped
 #: between disconnected node pairs.  ``rho2`` rides along so one row pairs
@@ -148,7 +149,8 @@ SIM_COLUMNS = [
 #: across rows with :func:`repro.core.workloads.spectral_rank_correlation`).
 WORKLOAD_COLUMNS = [
     "workload", "rho2", "step_time_ms", "compute_ms", "comm_dp_ms",
-    "comm_tp_ms", "comm_moe_ms", "comm_total_ms", "comm_exposed_frac",
+    "comm_tp_ms", "comm_moe_ms", "comm_pp_ms", "comm_total_ms",
+    "comm_exposed_frac",
     "workload_dropped_frac",
 ]
 
@@ -422,6 +424,7 @@ def _workload_values(a: Analysis, cfg: Dict[str, Any]) -> Dict[str, Any]:
         comm_dp_ms=_round(res.dp_seconds * 1e3),
         comm_tp_ms=_round(res.tp_seconds * 1e3),
         comm_moe_ms=_round(res.moe_seconds * 1e3),
+        comm_pp_ms=_round(res.pp_seconds * 1e3),
         comm_total_ms=_round(res.comm_seconds * 1e3),
         comm_exposed_frac=_round(res.exposed_comm_fraction, 4),
         workload_dropped_frac=_round(res.dropped_frac, 4),

@@ -20,7 +20,8 @@ import math
 from typing import Dict, Optional, Tuple
 
 __all__ = ["LayerSpec", "ArchConfig", "register", "get_config", "list_configs",
-           "SHAPES", "ShapeSpec", "cells_for"]
+           "SHAPES", "WORKLOAD_SHAPES", "PARAM_TABLES", "ShapeSpec",
+           "cells_for"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -221,6 +222,19 @@ SHAPES: Dict[str, ShapeSpec] = {
     "decode_32k": ShapeSpec("decode_32k", 32_768, 128, "decode"),
     "long_500k": ShapeSpec("long_500k", 524_288, 1, "decode"),
 }
+
+
+#: training shapes of published runs that only the workload planner reads
+#: (:func:`cells_for` never gives them to the LM stack)
+WORKLOAD_SHAPES: Dict[str, ShapeSpec] = {
+    # DeepSeek-V3 pre-training (arXiv:2412.19437, §4.2)
+    "train_4k_gb15360": ShapeSpec("train_4k_gb15360", 4096, 15360, "train"),
+}
+
+#: architectures known only as shape-only parameter tables (registry name ->
+#: module under :mod:`repro.configs`): no LM-stack model, so not in
+#: :func:`list_configs`; :mod:`repro.core.workloads` resolves them beside it
+PARAM_TABLES: Dict[str, str] = {"deepseek-v3": "deepseek_v3"}
 
 
 def reduced(cfg: ArchConfig, repeats: int = 2) -> ArchConfig:

@@ -348,7 +348,9 @@ def fault_sweep(topo: Topology, rates: Sequence[float] = (0.02, 0.05, 0.1, 0.2),
     simulate_workload`; each sample needs its own all-sources BFS, hence the
     small default), appending measured degraded step times per row:
     ``workload_step_mean/max`` (seconds), ``workload_dropped_frac_mean``
-    (fraction of the plan's demand between disconnected node pairs).
+    (fraction of the plan's demand between disconnected node pairs) and
+    ``workload_phase_seconds_mean`` (phase name -> mean link seconds of that
+    phase over the samples).
     """
     if model not in FAULT_MODELS:
         raise ValueError(f"unknown fault model {model!r} (known: {FAULT_MODELS})")
@@ -451,6 +453,10 @@ def fault_sweep(topo: Topology, rates: Sequence[float] = (0.02, 0.05, 0.1, 0.2),
                 np.max([w.step_seconds for w in wl]))
             row["workload_dropped_frac_mean"] = float(
                 np.mean([w.dropped_frac for w in wl]))
+            secs = [w.phase_seconds() for w in wl]
+            row["workload_phase_seconds_mean"] = {
+                name: float(np.mean([s[name] for s in secs]))
+                for name in secs[0]}
         rows.append(row)
     return FaultSweepResult(
         name=topo.name, model=model, n=topo.n, m=topo.m, samples=B_samples,

@@ -52,8 +52,8 @@ from .collectives import LINK_BW, PER_HOP_LATENCY
 from .graphs import Topology
 from .routing import (DEFAULT_SOURCE_CHUNK, RoutingResult, _bfs_dist_chunk,
                       _sigma_chunk, analyze_routing)
-from .traffic import (ROUTING_SCHEMES, _ecmp_loads_chunk, demand_matrix,
-                      scheme_link_loads)
+from .traffic import (ROUTING_SCHEMES, EcmpRouter, _ecmp_loads_chunk,
+                      demand_matrix, scheme_link_loads)
 
 __all__ = [
     "Schedule", "SimulationResult", "RoundTelemetry", "SIM_ALGORITHMS",
@@ -204,9 +204,13 @@ def _lower_demand_rounds(table: np.ndarray, routing: RoutingResult,
                                                   np.ndarray, float]:
     """Lower logical (demand, count) rounds onto the gather-table slots under
     one of the traffic layer's routing schemes (minimal ECMP by default —
-    Valiant/UGAL/ksp let executed collectives ride non-minimal paths)."""
+    Valiant/UGAL/ksp let executed collectives ride non-minimal paths).  The
+    minimal scheme routes every round over one :class:`EcmpRouter`, so the
+    routing goes to the device once."""
     dist = routing.dist
     reachable = dist >= 0
+    router = EcmpRouter(table, dist, routing.sigma, chunk=chunk) \
+        if scheme == "minimal" else None
     rounds, counts, hops = [], [], []
     dropped = 0.0
     for D, count, _scale in logical:
@@ -214,7 +218,8 @@ def _lower_demand_rounds(table: np.ndarray, routing: RoutingResult,
         np.fill_diagonal(served, 0.0)
         dropped += count * float(D.sum() - np.trace(D) - served.sum())
         loads, _, max_hops = scheme_link_loads(
-            table, routing, served, scheme, slack=slack, chunk=chunk)
+            table, routing, served, scheme, slack=slack, chunk=chunk,
+            router=router)
         rounds.append(loads.astype(np.float32))
         counts.append(count)
         hops.append(int(max_hops))

@@ -78,7 +78,7 @@ except ImportError:                    # pragma: no cover - scipy-less CI
 
 __all__ = [
     "TRAFFIC_PATTERNS", "ROUTING_SCHEMES", "TrafficResult", "demand_matrix",
-    "demand_rows", "ecmp_link_loads", "scheme_link_loads",
+    "demand_rows", "EcmpRouter", "ecmp_link_loads", "scheme_link_loads",
     "valiant_link_loads", "ugal_link_loads", "ksp_link_loads",
     "mcf_throughput_ub", "evaluate_traffic", "spectral_throughput_estimate",
 ]
@@ -246,11 +246,118 @@ def _ecmp_loads_chunk(table: jnp.ndarray, dist: jnp.ndarray,
     return jax.vmap(one)(dist, sigma, w).sum(axis=0)
 
 
+@functools.partial(jax.jit, static_argnames=("backend",))
+def _ecmp_pair_loads_chunk(table: jnp.ndarray, dist: jnp.ndarray,
+                           sigma: jnp.ndarray, src: jnp.ndarray,
+                           dst: jnp.ndarray, nbytes: jnp.ndarray,
+                           backend: Optional[str] = None) -> jnp.ndarray:
+    """:func:`_ecmp_loads_chunk` of a block of sources whose demand rows are
+    built here from node pairs: row ``src`` gets ``nbytes`` at column
+    ``dst``."""
+    obs.count("jit_trace/ecmp_pairs")            # trace-time increment
+    rows = jnp.zeros(dist.shape, jnp.float32).at[src, dst].add(nbytes)
+    return _ecmp_loads_chunk(table, dist, sigma, rows, backend=backend)
+
+
+class EcmpRouter:
+    """Minimal-ECMP link loads of any number of demands over one routing.
+
+    The padded table, and each chunk of sources' BFS distances and float32
+    path counts, go to the device on first use and stay there, so every
+    demand routed after reuses them.  Demand comes as (S, n) rows aligned
+    with the sources (:meth:`row_loads`) or, where the routing covers every
+    source, as node pairs (:meth:`pair_loads`); both give the same loads
+    for the same demand.
+
+    Args:
+        table: (n, k) padded neighbor table (``gather_operands()[0]``).
+        dist: (S, n) BFS distances from :func:`repro.core.routing.bfs_distances`.
+        sigma: (S, n) minimal-path counts matching ``dist``.
+        chunk: sources per jitted call.
+    """
+
+    def __init__(self, table: np.ndarray, dist: np.ndarray,
+                 sigma: np.ndarray, chunk: int = DEFAULT_SOURCE_CHUNK,
+                 backend: Optional[str] = None):
+        table = np.asarray(table)
+        self.shape = table.shape
+        self.table = jnp.asarray(table, dtype=jnp.int32)
+        self.dist = np.asarray(dist)
+        self.sigma = sigma
+        self.backend = backend
+        S = self.dist.shape[0]
+        self.bounds = [(lo, min(lo + chunk, S)) for lo in range(0, S, chunk)]
+        self._chunks: Dict[int, Tuple[jnp.ndarray, jnp.ndarray]] = {}
+
+    def _chunk(self, i: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        if i not in self._chunks:
+            lo, hi = self.bounds[i]
+            self._chunks[i] = (
+                jnp.asarray(self.dist[lo:hi]),
+                jnp.asarray(self.sigma[lo:hi], dtype=jnp.float32))
+        return self._chunks[i]
+
+    def row_loads(self, demands: np.ndarray) -> np.ndarray:
+        """(n, k) float64 directed loads of (S, n) demand rows in injection
+        units, one per BFS source (row s holds D[s, :]): entry ``[u, j]`` is
+        the load on directed link u → table[u, j] (padding slots stay 0;
+        parallel edges each get their ECMP share).  Demands to unreachable
+        targets are ignored (dropped, reported by :func:`evaluate_traffic`).
+        """
+        # a demand to an unreachable target would otherwise sit in g forever
+        demands = np.where(self.dist >= 0, demands, 0.0)
+        loads = np.zeros(self.shape, dtype=np.float64)
+        for i, (lo, hi) in enumerate(self.bounds):
+            dist, sigma = self._chunk(i)
+            loads += np.asarray(_ecmp_loads_chunk(
+                self.table, dist, sigma,
+                jnp.asarray(demands[lo:hi], dtype=jnp.float32),
+                backend=self.backend), dtype=np.float64)
+        return loads
+
+    def pair_loads(self, u: np.ndarray, v: np.ndarray, b: np.ndarray
+                   ) -> np.ndarray:
+        """(n, k) float64 directed loads of ``b[i]`` on node pair
+        ``u[i] → v[i]``, as :meth:`row_loads` lays them out.  Needs the
+        routing of every source (row ``s`` of ``dist`` is node ``s``).
+        Pairs to unreachable targets are ignored.  Each chunk's pairs go to
+        the device, bytes in float32, padded to a power of two so that a
+        chunk compiles once for pair counts up to it; the device builds the
+        demand rows.  A pair listed once gives the float32 value of its
+        bytes, as :meth:`row_loads`'s cast does; a pair listed twice adds
+        its two payloads in float32."""
+        if self.dist.shape[0] != self.shape[0]:
+            raise ValueError("pair demand needs an all-sources routing "
+                             f"(got {self.dist.shape[0]}/{self.shape[0]} "
+                             "sources)")
+        served = np.where(self.dist[u, v] >= 0, b, 0.0)
+        order = np.argsort(u, kind="stable")
+        u, v = u[order], v[order]
+        served = served[order].astype(np.float32)
+        loads = np.zeros(self.shape, dtype=np.float64)
+        for i, (lo, hi) in enumerate(self.bounds):
+            a, z = np.searchsorted(u, [lo, hi])
+            if z == a:
+                continue
+            cap = 1 << int(z - a - 1).bit_length()
+            src = np.zeros(cap, dtype=np.int32)
+            dst = np.zeros(cap, dtype=np.int32)
+            nbytes = np.zeros(cap, dtype=np.float32)
+            src[:z - a], dst[:z - a] = u[a:z] - lo, v[a:z]
+            nbytes[:z - a] = served[a:z]
+            dist, sigma = self._chunk(i)
+            loads += np.asarray(_ecmp_pair_loads_chunk(
+                self.table, dist, sigma, src, dst, nbytes,
+                backend=self.backend), dtype=np.float64)
+        return loads
+
+
 def ecmp_link_loads(table: np.ndarray, dist: np.ndarray, sigma: np.ndarray,
                     demands: np.ndarray,
                     chunk: int = DEFAULT_SOURCE_CHUNK,
                     backend: Optional[str] = None) -> np.ndarray:
-    """Directed link loads under minimal-path ECMP routing of ``demands``.
+    """Directed link loads under minimal-path ECMP routing of ``demands``:
+    :meth:`EcmpRouter.row_loads` of a router used once.
 
     Args:
         table: (n, k) padded neighbor table (``gather_operands()[0]``).
@@ -266,19 +373,8 @@ def ecmp_link_loads(table: np.ndarray, dist: np.ndarray, sigma: np.ndarray,
         ``[u, j]`` is the load on directed link u → table[u, j] (padding slots
         stay 0; parallel edges each get their ECMP share).
     """
-    table = np.asarray(table)
-    tab = jnp.asarray(table, dtype=jnp.int32)
-    # a demand to an unreachable target would otherwise sit in g forever
-    demands = np.where(dist >= 0, demands, 0.0)
-    loads = np.zeros(table.shape, dtype=np.float64)
-    for lo in range(0, dist.shape[0], chunk):
-        hi = min(lo + chunk, dist.shape[0])
-        loads += np.asarray(_ecmp_loads_chunk(
-            tab, jnp.asarray(dist[lo:hi]),
-            jnp.asarray(sigma[lo:hi], dtype=jnp.float32),
-            jnp.asarray(demands[lo:hi], dtype=jnp.float32),
-            backend=backend), dtype=np.float64)
-    return loads
+    return EcmpRouter(table, dist, sigma, chunk=chunk,
+                      backend=backend).row_loads(demands)
 
 
 @functools.partial(jax.jit, static_argnames=("backend",))
@@ -614,13 +710,17 @@ def ksp_link_loads(table: np.ndarray, routing: RoutingResult,
 def scheme_link_loads(table: np.ndarray, routing: RoutingResult,
                       served: np.ndarray, scheme: str = "minimal", *,
                       slack: int = 1, chunk: int = DEFAULT_SOURCE_CHUNK,
-                      backend: Optional[str] = None
+                      backend: Optional[str] = None,
+                      router: Optional[EcmpRouter] = None
                       ) -> Tuple[np.ndarray, float, int]:
     """Route served demand rows under one of :data:`ROUTING_SCHEMES`.
 
     The shared dispatch used by :func:`evaluate_traffic` and the simulator's
     schedule compiler.  ``served`` is (S, n) demand rows aligned with
     ``routing.sources`` (diagonal zeroed, unreachable targets dropped).
+    ``router``: an :class:`EcmpRouter` over ``routing`` whose operands are
+    already on the device, for the minimal scheme (a caller routing many
+    demands over one graph); one is made here when absent.
 
     Returns ``(loads, hops_weighted, max_hops)``: (n, k) float64 directed
     slot loads *before* any n/S sampling correction, the demand-weighted hop
@@ -630,8 +730,9 @@ def scheme_link_loads(table: np.ndarray, routing: RoutingResult,
     table = np.asarray(table)
     dist = routing.dist
     if scheme == "minimal":
-        loads = ecmp_link_loads(table, dist, routing.sigma, served,
-                                chunk=chunk, backend=backend)
+        router = router or EcmpRouter(table, dist, routing.sigma,
+                                      chunk=chunk, backend=backend)
+        loads = router.row_loads(served)
         reach = dist >= 0
         dpos = np.where(reach, dist, 0)
         sm = np.where(reach, served, 0.0)

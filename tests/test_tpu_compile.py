@@ -121,3 +121,34 @@ def test_lap_lanczos_shared_table_compiles(B, n, k, m, one_chip):
         sds((B, n), F32), m, backend="ref",
         counts=sds((B, n, k), F32)).compile()
     assert f"slice_sizes={{{B},1}}" in compiled.as_text()   # rows of B
+
+
+def test_pod_pair_lowering_compiles_at_every_pair_count(one_chip):
+    """The ECMP pass over node pairs at the DeepSeek-V3 pod cell's size: a
+    chunk of 512 sources of torus(16,3), at each power-of-two pair count
+    that a chunk of one of its phases pads to."""
+    import numpy as np
+
+    from repro.core import traffic as T
+    from repro.core import workloads as W
+    from repro.core.placement import place_ranks
+
+    n, k, S = 4096, 6, 512
+    plan = W.plan_workload("deepseek_v3@pp=16,dp=256,ep=64,"
+                           "shape=train_4k_gb15360")
+    node_of = place_ranks(n, plan.world, strategy="linear")
+    caps = set()
+    for phase in plan.phases:
+        u, _, _, _ = W._phase_pairs(
+            phase, W._phase_groups(plan, phase.group_axis), node_of)
+        per_chunk = np.bincount(u // S, minlength=n // S)
+        caps |= {1 << int(c - 1).bit_length() for c in per_chunk if c}
+    assert caps
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    for cap in sorted(caps):
+        T._ecmp_pair_loads_chunk.lower(
+            sds((n, k), I32), sds((S, n), I32), sds((S, n), F32),
+            sds((cap,), I32), sds((cap,), I32), sds((cap,), F32)).compile()
