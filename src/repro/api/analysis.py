@@ -467,7 +467,7 @@ class Analysis:
                     workload_samples: int = 2) -> "F.FaultSweepResult":
         """Survival curves under fault injection (rho2, bisection floor,
         connectivity vs fault rate).  Monte-Carlo models batch all ``samples``
-        degraded instances per rate into ONE vmapped Laplacian Lanczos solve;
+        degraded instances per rate into ONE batched Laplacian Lanczos solve;
         the adversarial models (``attack_degree``, ``attack_spectral``) are
         deterministic.  Reuses this session's cached healthy rho2 and (for the
         spectral attack) Fiedler vector.  ``routing=True`` additionally runs

@@ -10,7 +10,7 @@ Four fault models produce :class:`FaultScenario` records (which links/nodes
 fail), ``apply_faults`` materializes the degraded :class:`Topology`, and
 :func:`fault_sweep` drives the whole pipeline: for each fault rate it draws B
 Monte-Carlo samples, stacks their padded gather operands, and solves all B
-degraded graphs in ONE vmapped Laplacian Lanczos call
+degraded graphs in ONE batched Laplacian Lanczos call
 (:func:`repro.core.spectral.rho2_laplacian_batched` — the same padded-table
 operand contract as the ``cayley_spmv`` kernel).  Degraded graphs are
 irregular, so the sweep runs on L = D - A rather than the regular-only
@@ -309,7 +309,7 @@ def fault_sweep(topo: Topology, rates: Sequence[float] = (0.02, 0.05, 0.1, 0.2),
     For each rate, ``samples`` Monte-Carlo scenarios (or one, for the
     deterministic adversarial models) are materialized, their padded gather
     operands stacked, and all degraded rho_2 values solved in a single
-    vmapped Laplacian Lanczos call.  Connectivity is counted exactly on the
+    batched Laplacian Lanczos call.  Connectivity is counted exactly on the
     host (rho_2 of a disconnected sample is ~0 and its zero crossing is the
     connectivity signal, but the component count is cheap and unambiguous).
 

@@ -217,6 +217,59 @@ def _lanczos_scan(op: Callable, v0: jnp.ndarray, m: int
     return alphas, betas, V
 
 
+def _basis_rows(m: int) -> int:
+    """Rows of a batched Lanczos basis: the m + 1 vectors, rounded up to the
+    8 sublanes of a float32 (8, 128) tile, so that the (B·rows, n) array the
+    scan carries and the (B, rows, n) view its products read are one
+    layout (a bitcast, not a copy)."""
+    return -(-(m + 1) // 8) * 8
+
+
+def _lanczos_scan_batched(op: Callable, v0s: jnp.ndarray, m: int
+                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`_lanczos_scan` on a (B, n) block of start vectors at once.
+
+    ``op`` maps a (B, n) block to a (B, n) block.  Same recurrence, mask,
+    two passes and breakdown threshold as :func:`_lanczos_scan`; the products
+    are batched contractions over n.  The basis is carried 2-D, (B·rows, n)
+    (:func:`_basis_rows`), which keeps n on the lanes whatever layout the
+    operator's gather gives the vectors: held (B, m + 1, n), or vmapped from
+    the single-vector scan, XLA lays it out batch-minor after a row gather,
+    so each product reads the batch padded to 128 lanes.  Pad rows stay zero
+    and are masked out.  Returns (alphas (B, m), betas (B, m)).
+    """
+    obs.count("jit_trace/lanczos_scan")
+    B, n = v0s.shape
+    rows = _basis_rows(m)
+    v = v0s.astype(jnp.float32)
+    v = v / jnp.linalg.norm(v, axis=1, keepdims=True)
+    V0 = jnp.zeros((B, rows, n), dtype=jnp.float32).at[:, 0].set(v)
+    ein = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
+
+    def body(carry, j):
+        V, v, v_prev, beta_prev = carry
+        V = V.reshape(B, rows, n)
+        w = op(v) - beta_prev[:, None] * v_prev
+        alpha = ein("bn,bn->b", w, v)
+        w = w - alpha[:, None] * v
+        mask = (jnp.arange(rows) <= j).astype(jnp.float32)
+        for _ in range(2):  # two-pass full reorthogonalization
+            coeff = ein("bjn,bn->bj", V, w) * mask
+            w = w - ein("bjn,bj->bn", V, coeff)
+        beta = jnp.linalg.norm(w, axis=1)
+        ok = beta > 1e-7
+        v_next = jnp.where(ok[:, None], w / jnp.where(ok, beta, 1.0)[:, None],
+                           jnp.zeros_like(w))
+        beta = jnp.where(ok, beta, 0.0)
+        V = V.at[:, j + 1].set(v_next).reshape(B * rows, n)
+        return (V, v_next, v, beta), (alpha, beta)
+
+    _, (alphas, betas) = jax.lax.scan(
+        body, (V0.reshape(B * rows, n), v, jnp.zeros_like(v),
+               jnp.zeros((B,), jnp.float32)), jnp.arange(m))
+    return alphas.T, betas.T
+
+
 @functools.partial(jax.jit, static_argnames=("matvec", "m"))
 def lanczos_tridiag(matvec: Callable, v0: jnp.ndarray, m: int,
                     deflate: Optional[jnp.ndarray] = None
@@ -446,7 +499,7 @@ def _lap_lanczos_batched(tables: jnp.ndarray, weights: jnp.ndarray,
                          backend: Optional[str] = None, mesh=None,
                          counts: Optional[jnp.ndarray] = None
                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """vmapped ones-deflated *Laplacian* Lanczos over B same-shape tables.
+    """Ones-deflated *Laplacian* Lanczos over B same-shape tables at once.
 
     The adjacency batch (:func:`_lanczos_tridiag_batched`) needs regular
     graphs; this one applies L = D - A through the padded gather form, so it
@@ -458,8 +511,11 @@ def _lap_lanczos_batched(tables: jnp.ndarray, weights: jnp.ndarray,
     shared by the batch with per-sample slot ``counts`` (B, n, k) — the
     operands :func:`_shared_table` derives.  The rank of ``tables`` picks the
     gather at trace time: per-sample tables gather B·n·k scalars a step; the
-    shared table, vmapped with the batch on its minor axis, gathers n·k rows
-    of B (XLA lays the (B, n) vector out batch-minor for it).
+    shared table, vmapped over the batch, gathers n·k rows of B, for which
+    XLA transposes the (B, n) block to batch-minor and back (two copies of
+    B·n floats a step).  The recurrence runs on the whole block
+    (:func:`_lanczos_scan_batched`), so the basis keeps n on the lanes
+    whatever layout the gather gives the vectors.
 
     Deflation of the trivial 0 eigenpair (ones) is done by a rank-one SHIFT,
     not a projection: ``L + c * ones ones^T / n`` moves the ones eigenvalue to
@@ -473,22 +529,24 @@ def _lap_lanczos_batched(tables: jnp.ndarray, weights: jnp.ndarray,
     """
     bk = KS.resolve_backend(backend)
 
-    def run(tab, cnt, lw, deg, v0):
-        c = jnp.max(deg) + 2.0
+    def solve(tab, cnt, lw, deg, v0, tab_axis):
+        gather = jax.vmap(
+            lambda x, t, s, w: KS.spmv(x, t, w, signs=s, backend=bk),
+            in_axes=(0, tab_axis, 0, 0))
+        c = jnp.max(deg, axis=1, keepdims=True) + 2.0
 
         def op(x):
-            lx = deg * x - KS.spmv(x, tab, lw, signs=cnt, backend=bk)
-            return lx + c * jnp.mean(x)
+            lx = deg * x - gather(x, tab, cnt, lw)
+            return lx + c * jnp.mean(x, axis=1, keepdims=True)
 
-        alphas, betas, _ = _lanczos_scan(op, v0, m)
-        return alphas, betas
+        return _lanczos_scan_batched(op, v0, m)
 
     if tables.ndim == 2:
-        solve = jax.vmap(run, in_axes=(None, 0, 0, 0, 0))
-        return _mesh.over_batch(solve, mesh, shared=1)(
-            tables, counts, weights, degs, v0s)
-    solve = jax.vmap(lambda tab, lw, deg, v0: run(tab, None, lw, deg, v0))
-    return _mesh.over_batch(solve, mesh)(tables, weights, degs, v0s)
+        return _mesh.over_batch(functools.partial(solve, tab_axis=None), mesh,
+                                shared=1)(tables, counts, weights, degs, v0s)
+    return _mesh.over_batch(
+        lambda tab, lw, deg, v0: solve(tab, None, lw, deg, v0, 0),
+        mesh)(tables, weights, degs, v0s)
 
 
 def _shared_table(tables: np.ndarray, weights: np.ndarray
@@ -560,7 +618,7 @@ def rho2_laplacian_batched(tables: np.ndarray, weights: np.ndarray,
     deflation).  This is the fault-sweep engine: B degraded instances never
     cost B Python-level solves.
 
-    The batch axis streams through the vmapped solve in memory-bounded tiles
+    The batch axis streams through the batched solve in memory-bounded tiles
     (``batch_chunk`` samples each; default from
     :data:`DEFAULT_BATCH_TILE_BYTES` — tier-1 sizes always fit one tile, so
     results are identical to the unchunked solve).  Each tile is split over

@@ -381,7 +381,7 @@ def rewire_search(n: int, k: int, budget: int = DEFAULT_REWIRE_BUDGET,
 
     Starts from a random k-regular graph; each round proposes ``batch``
     candidates (each ``swap_fraction * m`` swaps away from the incumbent) and
-    scores incumbent + candidates in ONE vmapped Laplacian Lanczos solve,
+    scores incumbent + candidates in ONE batched Laplacian Lanczos solve,
     moving to the best.  ``budget`` is the total candidate evaluations
     (rounds = budget // (batch + 1)).  Reaches any (n, k) with n*k even —
     the sizes a power-of-two lift tower cannot hit.  Returns (topology,

@@ -219,6 +219,74 @@ def test_batched_solve_counts_its_gather(stack, shared):
     assert delta.get("lanczos/per_sample_tiles", 0) == int(not shared)
 
 
+def _cut_cycle_stack():
+    """A cycle cut into two paths in three samples and into one path in a
+    fourth: Lanczos on a 32-vertex graph breaks down before m = 40."""
+    g = T.cycle(32)
+    return [F.apply_faults(g, F.FaultScenario(
+        kind="link", rate=len(cut) / 32, seed=0, failed_links=np.array(cut),
+        failed_nodes=np.empty(0, dtype=np.int64)))
+        for cut in ([0, 16], [3, 20], [5, 6], [7])]
+
+
+@pytest.mark.parametrize("stack,shared", [("torus-links", True),
+                                          ("node-faults", False),
+                                          ("cut-cycle", True)])
+def test_batched_scan_matches_single_vector_scan(stack, shared):
+    """The block recurrence gives each sample the single-vector scan's
+    (alpha, beta) on the same operator, breakdown zeros included, and the
+    batched rho_2 still matches the dense oracle."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import spmv as KS
+
+    degraded = dict(SHARED_STACKS, **{"node-faults": _node_stack,
+                                      "cut-cycle": _cut_cycle_stack})[stack]()
+    tabs, ws, degs = F.stacked_operands(degraded)
+    B, n, _ = tabs.shape
+    m = 40
+    v0s = jax.random.normal(jax.random.PRNGKey(3), (B, n), dtype=jnp.float32)
+    degs32 = jnp.asarray(degs, dtype=jnp.float32)
+    if shared:
+        U, C, w2 = S._shared_table(tabs, ws)[1]
+        alphas, betas = S._lap_lanczos_batched(
+            jnp.asarray(U), jnp.asarray(w2), degs32, v0s, m, backend="ref",
+            counts=jnp.asarray(C))
+        operands = [(U, w2[b], C[b]) for b in range(B)]
+    else:
+        alphas, betas = S._lap_lanczos_batched(
+            jnp.asarray(tabs), jnp.asarray(ws, dtype=jnp.float32), degs32,
+            v0s, m, backend="ref")
+        operands = [(tabs[b], ws[b], None) for b in range(B)]
+    assert alphas.shape == betas.shape == (B, m)
+    for b, (tab, lw, cnt) in enumerate(operands):
+        deg = degs32[b]
+
+        def op(x):
+            y = KS.spmv(x, jnp.asarray(tab), jnp.asarray(lw, jnp.float32),
+                        signs=None if cnt is None else jnp.asarray(cnt),
+                        backend="ref")
+            return deg * x - y + (jnp.max(deg) + 2.0) * jnp.mean(x)
+
+        a1, b1, _ = S._lanczos_scan(op, v0s[b], m)
+        a1, b1 = np.asarray(a1), np.asarray(b1)
+        assert np.abs(np.asarray(alphas[b]) - a1).max() \
+            <= 1e-5 * np.abs(a1).max()
+        assert np.abs(np.asarray(betas[b]) - b1).max() \
+            <= 1e-5 * np.abs(b1).max()
+        assert np.array_equal(np.asarray(betas[b]) == 0.0, b1 == 0.0)
+
+    before = obs.counters("lanczos/")
+    got = S.rho2_laplacian_batched(tabs, ws, degs, iters=m, seed=0,
+                                   backend="ref")
+    delta = obs.counter_delta(before, "lanczos/")
+    assert (delta.get("lanczos/breakdown_truncations", 0) > 0) == \
+        (stack == "cut-cycle")
+    want = np.array([max(S.laplacian_spectrum(d)[1], 0.0) for d in degraded])
+    assert np.abs(got - want).max() < 1e-3
+
+
 def test_link_sweep_second_rate_does_not_retrace():
     g = T.torus(8, 2)
     F.fault_sweep(g, rates=(0.05,), samples=8, seed=0, iters=30)

@@ -123,6 +123,34 @@ def test_lap_lanczos_shared_table_compiles(B, n, k, m, one_chip):
     assert f"slice_sizes={{{B},1}}" in compiled.as_text()   # rows of B
 
 
+@pytest.mark.parametrize("B,n,k,m,shared", [
+    (32, 4096, 6, 200, True), (8, 4096, 6, 200, True),
+    (4, 4096, 6, 200, True), (32, 4096, 6, 200, False)])
+def test_lap_lanczos_basis_keeps_vertices_on_the_lanes(B, n, k, m, shared,
+                                                       one_chip):
+    """The batched Laplacian Lanczos basis is laid out with n minor-most at
+    every tile size a chip takes, through either gather: a batch axis on the
+    lanes would pad each re-orthogonalisation product's reads to 128
+    samples."""
+    import re
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tab = sds((n, k), I32) if shared else sds((B, n, k), I32)
+    counts = sds((B, n, k), F32) if shared else None
+    compiled = S._lap_lanczos_batched.lower(
+        tab, sds((B, n), F32), sds((B, n), F32), sds((B, n), F32), m,
+        backend="ref", counts=counts).compile()
+    text = compiled.as_text()
+    rows = S._basis_rows(m)
+    layouts = re.findall(
+        rf"f32\[(?:{B},(?:{rows}|{m + 1}),{n}|{B * rows},{n})\]\{{([^}}:]*)",
+        text)
+    assert any(lay.startswith("2,") for lay in layouts), layouts  # (B, rows, n)
+    assert not [lay for lay in layouts if lay.startswith("0,")], layouts
+
+
 def test_pod_pair_lowering_compiles_at_every_pair_count(one_chip):
     """The ECMP pass over node pairs at the DeepSeek-V3 pod cell's size: a
     chunk of 512 sources of torus(16,3), at each power-of-two pair count
